@@ -93,8 +93,9 @@ Every invocation also merges its rows into ``BENCH_simx.json`` — a JSON
 array keyed by (git rev, bench name), the machine-readable trajectory
 that makes speed/overhead regressions diffable across PRs (disable with
 ``--bench-json none``).  Unless ``--no-compile-cache`` is passed, the
-persistent JAX compilation cache is enabled (``JAX_COMPILE_CACHE_DIR``
-or ``.jax_compile_cache``) so bench reruns and CI smoke steps stop
+persistent JAX compilation cache is enabled (``JAX_COMPILATION_CACHE_DIR``
+or ``<checkout>/.jax_compile_cache``, ``repro.compile_cache``) so bench
+reruns and CI smoke steps stop
 paying the per-rule recompile; the point-ladder rows report
 ``compile_s`` (cold, first build) next to ``compile_warm_s`` (a fresh
 AOT build of the same program, which hits the persistent cache).
@@ -107,6 +108,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.sim.simulator import run_simulation
 from repro.simx import engine as sxe
 from repro.simx import megha as sxm
@@ -160,28 +162,6 @@ SHARDED_STEADY = dict(
 
 #: This invocation's machine-readable rows (mirrors the printed CSV).
 _BENCH_ROWS: list[dict] = []
-
-
-def enable_compile_cache(path: str | None = None) -> str:
-    """Point jax at a persistent on-disk compilation cache so re-runs of
-    the bench skip XLA compiles entirely (``compile_s`` cold vs
-    ``compile_warm_s`` warm in the dc rows).  Path resolution:
-    explicit arg > ``$JAX_COMPILE_CACHE_DIR`` > ``.jax_compile_cache``.
-    The thresholds are zeroed because bench programs are many small
-    scans — the default 1s/min-size gates would skip all of them."""
-    import os
-
-    path = path or os.environ.get("JAX_COMPILE_CACHE_DIR") or ".jax_compile_cache"
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax latches cache-enablement on the FIRST compile of the process —
-    # any import-time jit before this call would pin "disabled" for good;
-    # reset the latch so the next compile re-checks the config above
-    from jax._src import compilation_cache
-
-    compilation_cache.reset_cache()
-    return path
 
 
 def _record(name: str, us: float, **derived) -> str:
@@ -951,7 +931,8 @@ if __name__ == "__main__":
     ap.add_argument("--no-compile-cache", action="store_true",
                     help="skip enabling the persistent JAX compilation "
                          "cache (on by default; dir from "
-                         "$JAX_COMPILE_CACHE_DIR or .jax_compile_cache)")
+                         "$JAX_COMPILATION_CACHE_DIR or "
+                         "<checkout>/.jax_compile_cache)")
     ap.add_argument("--trace-out", default="simx_trace.json",
                     help="Chrome-trace JSON output path (default "
                          "simx_trace.json)")

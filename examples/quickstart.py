@@ -72,7 +72,7 @@ print(f"  GM_B view now equals ground truth: {bool(jnp.array_equal(r2.view, r2.t
 
 print()
 print("=" * 70)
-print("4) Pallas match kernel (interpret mode) vs jnp oracle")
+print("4) Pallas match kernel (interpreted on the CPU) vs jnp oracle")
 print("=" * 70)
 from repro.kernels import ops, ref
 

@@ -65,7 +65,7 @@ class RoundResult(NamedTuple):
     n_inconsistent: jax.Array
 
 
-@functools.partial(jax.jit, static_argnames=("max_tasks", "use_pallas", "interpret"))
+@functools.partial(jax.jit, static_argnames=("max_tasks", "use_pallas"))
 def gm_round(
     truth: jax.Array,
     view: jax.Array,
@@ -74,7 +74,6 @@ def gm_round(
     *,
     max_tasks: int,
     use_pallas: bool = True,
-    interpret: bool = True,
 ) -> RoundResult:
     """One GM scheduling round against the LM ground truth.
 
@@ -87,7 +86,7 @@ def gm_round(
     """
     avail_ordered = view[order]  # GM's priority-ordered availability
     asg_pos, _ = kops.match_tasks(
-        avail_ordered, n_tasks, max_tasks, use_pallas=use_pallas, interpret=interpret
+        avail_ordered, n_tasks, max_tasks, use_pallas=use_pallas
     )
     workers = jnp.where(asg_pos >= 0, order[jnp.clip(asg_pos, 0, order.shape[0] - 1)], -1)
     new_truth, valid = kops.verify_and_commit(truth, workers)

@@ -17,7 +17,7 @@ from repro.kernels import ref
 
 
 @functools.partial(
-    jax.jit, static_argnames=("max_tasks", "use_pallas", "interpret", "block_rows")
+    jax.jit, static_argnames=("max_tasks", "use_pallas", "block_rows")
 )
 def match_tasks(
     avail: jax.Array,
@@ -25,7 +25,6 @@ def match_tasks(
     max_tasks: int,
     *,
     use_pallas: bool = True,
-    interpret: bool = True,
     block_rows: int = 64,
 ) -> tuple[jax.Array, jax.Array]:
     """Match up to ``n_tasks`` tasks onto free workers in priority order.
@@ -42,9 +41,7 @@ def match_tasks(
     """
     n = jnp.minimum(jnp.asarray(n_tasks, jnp.int32), max_tasks)
     if use_pallas:
-        ranks = match_kernel.match_ranks(
-            avail, n, block_rows=block_rows, interpret=interpret
-        )
+        ranks = match_kernel.match_ranks(avail, n, block_rows=block_rows)
     else:
         ranks = ref.match_ranks_ref(avail, n)
     w = avail.shape[0]

@@ -526,7 +526,6 @@ def simulate_workload(
     max_rounds: Optional[int] = None,
     until: Optional[float] = None,
     use_pallas: bool = False,
-    interpret: bool = True,
     faults: FaultSchedule | FaultPlan | None = None,
     telemetry: TelemetryConfig | bool | None = None,
     provenance: bool = False,
@@ -595,12 +594,10 @@ def simulate_workload(
         if is_empty(faults):
             faults = None  # the no-op schedule: build the plain program
     key = jax.random.PRNGKey(seed)
-    match_fn = runtime.default_match_fn(use_pallas=use_pallas, interpret=interpret)
+    match_fn = runtime.default_match_fn(use_pallas=use_pallas)
     # the [W, R] head-of-queue pick wants a 1-row-block kernel tile (queue
     # rows are R <= 64 wide; the wide match's default would pad them 64x)
-    pick_fn = runtime.default_match_fn(
-        use_pallas=use_pallas, interpret=interpret, block_rows=1
-    )
+    pick_fn = runtime.default_match_fn(use_pallas=use_pallas, block_rows=1)
     if telemetry is True:
         telemetry = TelemetryConfig()
     # any registered rule builds and runs through the same three calls

@@ -75,12 +75,14 @@ from repro.simx.state import QueueState, SimxConfig, TaskArrays
 MatchFn = Callable[[jax.Array, jax.Array], jax.Array]
 
 
-def default_match_fn(
-    use_pallas: bool = False, interpret: bool = True, block_rows: int = 64
-) -> MatchFn:
-    """The match primitive every rule ranks-and-selects with: the batched
-    Pallas kernel on TPU, the jnp reference on CPU (Pallas interpret mode
-    is orders of magnitude slower than XLA inside a scanned hot loop).
+def default_match_fn(use_pallas: bool = False, block_rows: int = 64) -> MatchFn:
+    """The match primitive every rule ranks-and-selects with: the jnp
+    reference on every platform by default.  ``use_pallas=True`` selects
+    the batched Pallas kernel instead; it stays opt-in until a chip
+    benchmark has measured it against the jnp scan.  The kernel is
+    compiled on a TPU and interpreted on the CPU
+    (``repro.kernels.match.default_interpret``) — interpret mode is orders
+    of magnitude slower than XLA inside a scanned hot loop.
 
     ``block_rows`` sizes the kernel's VMEM tile; the kernel pads each row
     to ``block_rows * 128`` lanes, so wide-and-few matches (megha's
@@ -88,7 +90,7 @@ def default_match_fn(
     narrow-and-many ones (the sparrow/eagle [W, R] head-of-queue pick,
     R ≲ 64) should pass ``block_rows=1``."""
     if use_pallas:
-        return partial(match_ranks_batched, interpret=interpret, block_rows=block_rows)
+        return partial(match_ranks_batched, block_rows=block_rows)
     return ref.match_ranks_batched_ref
 
 

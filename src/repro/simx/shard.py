@@ -27,21 +27,20 @@ across a 1-D ``"grid"`` mesh axis instead:
     lane axis sharded across the mesh — a whole tail-latency-vs-load
     curve as one mesh-parallel program.
 
-**Why pmap and not shard_map / GSPMD.**  Both "modern" executors
-miscompile this workload on multi-device CPU (jax 0.4.37, forced host
-devices).  A ``NamedSharding``-constrained jit hands the vmapped scan to
-GSPMD, which inserts an AllGather on an intermediate it decides to
-replicate — and the CPU collective rendezvous for it deadlocks under
-``--xla_force_host_platform_device_count``.  ``shard_map`` (with
-``check_rep=False``) compiles and runs, but the per-point PRNG key — a
-loop-invariant input of the round scan — comes out of lowering with
-*shard 0's value broadcast to every device*: every grid point simulates
-with the first point's seed.  The collapse is silent (fixed-seed grids
-agree; only seed-sensitive fault grids expose it) and survives
-precomputing the keys outside the sharded region, so this module pins
-parity with per-point-distinct seeds in ``tests/test_simx_shard.py`` and
-uses ``pmap``, whose per-device lowering reproduces the serial grids
-bit-for-bit.
+**The executor.**  The drivers call ``jax.pmap``.  On JAX 0.9
+(``jax_pmap_shmap_merge`` on by default) ``pmap`` is built on
+``shard_map``: it lowers to one SPMD program partitioned over the mesh's
+devices (``num_partitions`` = device count, no collective), each
+partition running the vmapped point function over its batch slice.  On
+four TPU v5e chips and on forced CPU devices it reproduces the serial
+grids — p50/p95 at rtol 1e-5, counts exactly — including the
+seed-sensitive fault grid, so every point keeps its own PRNG key.
+Under jax 0.4.37 the alternatives failed on forced CPU devices: a
+``NamedSharding``-constrained jit made GSPMD insert an AllGather whose
+CPU rendezvous deadlocked, and a hand-written ``shard_map`` broadcast
+shard 0's loop-invariant PRNG key to every device (silent on fixed-seed
+grids).  ``tests/test_simx_shard.py::test_fault_grid_is_seed_sensitive``
+keeps guarding that bug class.
 
 **Pad-and-mask semantics.**  A batch of B real points is padded to the
 next device multiple by repeating the last real point; the pad points
@@ -171,8 +170,8 @@ def _batched_runner(
     """Wrap a per-point function into a zero-arg runner: pad the batch to
     a device multiple, reshape it to ``[n_dev, per_dev, ...]``, run the
     vmapped point under ``jax.pmap`` over the mesh's devices (each device
-    sweeps its local batch slice — no collective in the program; see the
-    module docstring for why not shard_map/GSPMD), and slice/reshape the
+    sweeps its local batch slice — no collective in the program; the
+    module docstring describes the executor), and slice/reshape the
     outputs back to ``[rows, cols]`` on the host.  The runner can be
     called repeatedly — the compiled program is reused, which is how the
     bench separates compile wall from steady-state wall."""
@@ -372,7 +371,7 @@ def _batched_segment(
     ``jax.pmap`` over the mesh's devices — every batched arg (state,
     window tasks, layout, sketch) arrives as ``[n_dev, per_dev, ...]``,
     each device advances its local lanes, and no collective appears in
-    the compiled program (module docstring: why not shard_map/GSPMD).
+    the compiled program (the module docstring describes the executor).
     Memoized like ``stream._default_segment`` — every refill, and every
     same-shaped sweep, reuses one compilation.  Lanes must share one
     ``SimxConfig`` (the layouts' static capacities then agree, which is

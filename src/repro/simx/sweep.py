@@ -378,7 +378,6 @@ def fig2_plan(
     slack: float = 4.0,
     trace_seed: int = 0,
     use_pallas: bool = False,
-    interpret: bool = True,
     mem_limit_gb: Optional[float] = 16.0,
     provenance: bool = False,
     **cfg_kwargs,
@@ -423,10 +422,8 @@ def fig2_plan(
         job_submit_grid=job_submit_g,
         seeds=jnp.arange(num_seeds),
         num_rounds=num_rounds,
-        match_fn=default_match_fn(use_pallas=use_pallas, interpret=interpret),
-        pick_fn=default_match_fn(
-            use_pallas=use_pallas, interpret=interpret, block_rows=1
-        ),
+        match_fn=default_match_fn(use_pallas=use_pallas),
+        pick_fn=default_match_fn(use_pallas=use_pallas, block_rows=1),
         provenance=provenance,
         annotate={
             "loads": np.asarray(loads),
@@ -448,7 +445,6 @@ def fig2_sweep(
     slack: float = 4.0,
     trace_seed: int = 0,
     use_pallas: bool = False,
-    interpret: bool = True,
     mem_limit_gb: Optional[float] = 16.0,
     provenance: bool = False,
     **cfg_kwargs,
@@ -458,9 +454,9 @@ def fig2_sweep(
 
     The defaults mirror the paper's synthetic trace (jobs of 1000 one-second
     tasks) at Fig. 2 scale; ``benchmarks/bench_simx.py --full`` drives this
-    at 50k workers.  On TPU hosts pass ``use_pallas=True`` (and
-    ``interpret=False``) to run the rank-and-select match as a compiled
-    Pallas kernel.  ``mem_limit_gb`` bounds the reservation-queue probe
+    at 50k workers.  ``use_pallas=True`` runs the rank-and-select match
+    as the Pallas kernel (compiled on a TPU, interpreted on the CPU).
+    ``mem_limit_gb`` bounds the reservation-queue probe
     state sparrow/eagle grids materialize (fail fast, not mid-compile OOM;
     None disables) — with the O(W * R) encoding it is MBs per point and
     the default ceiling never binds at paper scale.
@@ -469,7 +465,7 @@ def fig2_sweep(
         scheduler,
         loads=loads, num_seeds=num_seeds, num_workers=num_workers,
         num_jobs=num_jobs, tasks_per_job=tasks_per_job, dt=dt, slack=slack,
-        trace_seed=trace_seed, use_pallas=use_pallas, interpret=interpret,
+        trace_seed=trace_seed, use_pallas=use_pallas,
         mem_limit_gb=mem_limit_gb, provenance=provenance, **cfg_kwargs,
     )
     out = sweep_grid(
@@ -538,7 +534,6 @@ def fig4_plan(
     trace_seed: int = 0,
     fault_seed: int = 0,
     use_pallas: bool = False,
-    interpret: bool = True,
     mem_limit_gb: Optional[float] = 16.0,
     **cfg_kwargs,
 ) -> FaultPlan:
@@ -591,10 +586,8 @@ def fig4_plan(
         schedules=schedules,
         seeds=jnp.arange(num_seeds),
         num_rounds=num_rounds,
-        match_fn=default_match_fn(use_pallas=use_pallas, interpret=interpret),
-        pick_fn=default_match_fn(
-            use_pallas=use_pallas, interpret=interpret, block_rows=1
-        ),
+        match_fn=default_match_fn(use_pallas=use_pallas),
+        pick_fn=default_match_fn(use_pallas=use_pallas, block_rows=1),
         annotate={
             "fractions": np.asarray(fractions),
             "fail_time": np.asarray(fail_time),
@@ -623,7 +616,6 @@ def fig4_sweep(
     trace_seed: int = 0,
     fault_seed: int = 0,
     use_pallas: bool = False,
-    interpret: bool = True,
     mem_limit_gb: Optional[float] = 16.0,
     **cfg_kwargs,
 ) -> dict[str, np.ndarray]:
@@ -645,7 +637,7 @@ def fig4_sweep(
         num_seeds=num_seeds, load=load, num_workers=num_workers,
         num_jobs=num_jobs, tasks_per_job=tasks_per_job, dt=dt, slack=slack,
         trace_seed=trace_seed, fault_seed=fault_seed, use_pallas=use_pallas,
-        interpret=interpret, mem_limit_gb=mem_limit_gb, **cfg_kwargs,
+        mem_limit_gb=mem_limit_gb, **cfg_kwargs,
     )
     out = fault_sweep_grid(
         plan.name, plan.cfg, plan.tasks, plan.schedules, plan.seeds,

@@ -13,7 +13,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import fastpath as FP
 from repro.kernels import ops, ref
-from repro.kernels.match import match_ranks
+from repro.kernels.match import match_ranks, match_ranks_batched
 
 
 @pytest.mark.parametrize("w", [1, 100, 128, 1024, 8192, 50_000])
@@ -23,7 +23,7 @@ def test_match_kernel_allclose_shapes_dtypes(w, dtype):
     avail = (rng.random(w) < 0.4)
     a = jnp.asarray(avail).astype(dtype)
     for n in (0, 1, w // 2, w):
-        got = match_ranks(a, n, interpret=True)
+        got = match_ranks(a, n)
         want = ref.match_ranks_ref(a, n)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -32,9 +32,45 @@ def test_match_kernel_allclose_shapes_dtypes(w, dtype):
 def test_match_kernel_block_shape_invariance(block_rows):
     rng = np.random.default_rng(0)
     a = jnp.asarray((rng.random(4096) < 0.5).astype(np.int8))
-    got = match_ranks(a, 1000, block_rows=block_rows, interpret=True)
+    got = match_ranks(a, 1000, block_rows=block_rows)
     want = ref.match_ranks_ref(a, 1000)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize(
+    "case,w,block_rows",
+    [
+        ("n_zero", 1000, 8),            # no task to place: every rank is -1
+        ("n_above_free", 1000, 8),      # fewer free workers than tasks
+        ("all_free", 4096, 8),          # every lane and row of a tile is free
+        ("ragged_w", 3 * 1024 + 77, 8),  # W not a multiple of the block
+        ("one_row_blocks", 2 * 128 + 5, 1),  # the [W, R] pick's tile
+    ],
+)
+def test_tile_scan_edge_cases(case, w, block_rows):
+    """The in-tile triangular-matmul scan against the jnp cumsum, at the
+    edges of a tile: the row offsets, the cross-block carry and the
+    padding of the last block all take part."""
+    rng = np.random.default_rng(w)
+    g = 3
+    if case == "all_free":
+        avail = np.ones((g, w), np.int8)
+    else:
+        avail = (rng.random((g, w)) < 0.4).astype(np.int8)
+    free = avail.sum(axis=1)
+    n = {
+        "n_zero": np.zeros(g),
+        "n_above_free": free + 5,
+        "all_free": np.array([0, w // 2, w]),
+    }.get(case, rng.integers(0, w + 1, g))
+    a, nt = jnp.asarray(avail), jnp.asarray(n, jnp.int32)
+    got = np.asarray(match_ranks_batched(a, nt, block_rows=block_rows))
+    want = np.asarray(ref.match_ranks_batched_ref(a, nt))
+    np.testing.assert_array_equal(got, want)
+    if case == "n_zero":
+        assert (got == -1).all()
+    if case == "n_above_free":
+        assert ((got >= 0).sum(axis=1) == free).all()
 
 
 @settings(max_examples=30, deadline=None)

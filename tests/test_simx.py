@@ -140,7 +140,7 @@ def test_simx_pallas_match_matches_ref_backend(small):
     ref_run = simx_megha.simulate_fixed(cfg, tasks, 0, rounds)
     pal_run = simx_megha.simulate_fixed(
         cfg, tasks, 0, rounds,
-        match_fn=simx_megha.default_match_fn(use_pallas=True, interpret=True),
+        match_fn=simx_megha.default_match_fn(use_pallas=True),
     )
     assert jnp.array_equal(ref_run.task_finish, pal_run.task_finish)
 
@@ -150,7 +150,7 @@ def test_match_ranks_batched_vs_ref(g, w):
     rng = np.random.default_rng(g * 1000 + w)
     avail = jnp.asarray((rng.random((g, w)) < 0.4).astype(np.int8))
     n = jnp.asarray(rng.integers(0, w + 1, g), jnp.int32)
-    got = match_ranks_batched(avail, n, interpret=True)
+    got = match_ranks_batched(avail, n)
     want = match_ranks_batched_ref(avail, n)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # each GM row assigns ranks 0..k-1 exactly once

@@ -391,7 +391,7 @@ def test_carried_state_independent_of_trace_length():
 
 def test_sparrow_queue_pick_via_pallas_kernel_matches_ref(small):
     """The head-of-queue pick routed through the Pallas rank-and-select
-    kernel (interpret mode, block_rows=1 for the narrow [W, R] rows)
+    kernel (interpreted on the CPU, block_rows=1 for the narrow [W, R] rows)
     reproduces the jnp reference path bitwise."""
     from repro.simx.megha import default_match_fn
 
@@ -401,7 +401,7 @@ def test_sparrow_queue_pick_via_pallas_kernel_matches_ref(small):
     ref_run = simx_sparrow.simulate_fixed(cfg, tasks, 1, rounds)
     pal_run = simx_sparrow.simulate_fixed(
         cfg, tasks, 1, rounds,
-        match_fn=default_match_fn(use_pallas=True, interpret=True, block_rows=1),
+        match_fn=default_match_fn(use_pallas=True, block_rows=1),
     )
     assert jnp.array_equal(ref_run.task_finish, pal_run.task_finish)
     assert jnp.array_equal(ref_run.worker_finish, pal_run.worker_finish)

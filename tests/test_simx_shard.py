@@ -15,9 +15,10 @@ and under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the CI
 sharded-smoke step) the same tests pin true multi-device parity.
 
 ``test_fault_grid_is_seed_sensitive`` is a regression pin for the bug
-class that forced the pmap executor: ``shard_map`` on this CPU stack
-broadcast shard 0's per-point PRNG key to every device, an error that
-fixed-seed grids cannot see.  It asserts distinct per-point seeds produce
+class that forced the pmap executor: under jax 0.4.37 a hand-written
+``shard_map`` broadcast shard 0's per-point PRNG key to every device, an
+error that fixed-seed grids cannot see (on JAX 0.9 ``pmap`` itself is
+built on ``shard_map``).  It asserts distinct per-point seeds produce
 their own (serial-matching) numbers through the sharded path.
 """
 
@@ -219,33 +220,46 @@ def test_sweep_grid_donation_parity():
         _close(donated[key], kept[key], err_msg=key)
 
 
-def test_compile_cache_knob(tmp_path):
-    """`bench_simx.enable_compile_cache` points jax at a persistent cache
-    dir and zeroes the size/time admission thresholds."""
-    import os
-    import sys
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "checkout"])
+def test_compile_cache_knob(env_set, tmp_path, monkeypatch):
+    """`repro.compile_cache.enable_compile_cache` leaves a cache directory
+    placed by ``$JAX_COMPILATION_CACHE_DIR`` alone, and otherwise resolves
+    the same absolute in-checkout directory from any working directory;
+    either way it zeroes the size/time admission thresholds."""
+    from pathlib import Path
 
-    sys.path.insert(
-        0, os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.compile_cache import enable_compile_cache
+
+    keys = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
     )
+    saved = {k: getattr(jax.config, k) for k in keys}
     try:
-        from bench_simx import enable_compile_cache
-    finally:
-        sys.path.pop(0)
-    from jax._src import compilation_cache
-
-    saved = {
-        k: getattr(jax.config, k)
-        for k in (
-            "jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes",
-        )
-    }
-    try:
-        where = enable_compile_cache(str(tmp_path / "jaxcache"))
-        assert where.endswith("jaxcache")
-        assert jax.config.jax_compilation_cache_dir == where
+        if env_set:
+            # JAX reads the variable itself when it starts; the helper must
+            # not override what the environment placed
+            env_dir = str(tmp_path / "from_env")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            jax.config.update("jax_compilation_cache_dir", env_dir)
+            assert enable_compile_cache() == env_dir
+            assert jax.config.jax_compilation_cache_dir == env_dir
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(
+                Path(__file__).resolve().parents[1] / ".jax_compile_cache"
+            )
+            seen = []
+            for cwd in (tmp_path, Path(__file__).parent):
+                monkeypatch.chdir(cwd)
+                seen.append(enable_compile_cache())
+            assert seen == [want, want]
+            assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
     finally:
         # the knob is process-global — leaked on, it corrupts later
         # suites (the orbax checkpoint tests abort under an active cache)
