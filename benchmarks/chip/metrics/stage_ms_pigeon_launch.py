@@ -1,0 +1,10 @@
+"""Device milliseconds per simulated round of one datacenter spent in
+pigeon's launch and head advance (``simx.pigeon.launch``): op seconds of
+the traced window attributed by ``stages.stage_s``, over the rounds
+times the datacenters (profiler trace and the runner's optimized HLO)."""
+
+import stages
+
+
+def read(w):
+    return stages.stage_ms(w, "simx.pigeon.launch")

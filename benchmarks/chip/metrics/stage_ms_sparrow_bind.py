@@ -1,0 +1,11 @@
+"""Device milliseconds per simulated round of one datacenter spent in
+sparrow's late binding: head pick, orphan rescue, launch
+(``simx.sparrow.bind``): op seconds of the traced window attributed by
+``stages.stage_s``, over the rounds times the datacenters (profiler
+trace and the runner's optimized HLO)."""
+
+import stages
+
+
+def read(w):
+    return stages.stage_ms(w, "simx.sparrow.bind")

@@ -81,40 +81,30 @@ FOUR_FIG4 = dict(fractions=(0.0, 0.05, 0.1), num_seeds=4, num_workers=64,
 
 
 class _Clock:
-    """XLA compile seconds and persistent-cache hits, from JAX's
-    monitoring events, accumulated while a phase runs."""
+    """Per phase: a ``repro.simx.spans`` span (on the profiler's clock),
+    and the XLA compile seconds and persistent-cache hits and misses the
+    program's compile counter records while it runs."""
 
     def __init__(self):
-        import jax
+        from repro.simx import spans
 
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+        self.spans = spans
 
     @contextlib.contextmanager
     def phase(self, name: str, **sizes):
         """Print one line for the phase: its sizes, the counts its body
         adds to the yielded dict, and its wall and compile seconds."""
-        c0, h0, m0 = self.compile_s, self.hits, self.misses
+        spans = self.spans
+        c0 = spans.phase_s("backend")
+        h0, m0 = spans.cache["hits"], spans.cache["misses"]
         info: dict = {}
         t0 = time.perf_counter()
-        yield info
+        with spans.span(f"chip_smoke.{name}"):
+            yield info
         fields = {**sizes, **info, "wall_s": time.perf_counter() - t0,
-                  "compile_s": self.compile_s - c0,
-                  "cache_hits": self.hits - h0,
-                  "cache_misses": self.misses - m0}
+                  "compile_s": spans.phase_s("backend") - c0,
+                  "cache_hits": spans.cache["hits"] - h0,
+                  "cache_misses": spans.cache["misses"] - m0}
         print(f"phase={name} " + " ".join(f"{k}={v}" for k, v in fields.items()),
               flush=True)
 

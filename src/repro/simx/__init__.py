@@ -8,7 +8,11 @@ fixed-timestep synchronous rounds over dense arrays, advanced under
 compiles a whole (seed x load) grid into one program).  Every scheduler is
 a ``Rule`` on the shared round-stage runtime (``repro.simx.runtime``);
 select the backend via ``run_simulation(..., backend="simx")``.
+
+Importing the package registers ``repro.simx.spans``' compile counter.
 """
+
+from repro.simx import spans  # noqa: F401 — registers the compile counter
 
 from repro.simx.engine import (
     SimxRun,

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.simx import runtime as rt
+from repro.simx import runtime as rt, spans
 from repro.simx.faults import (
     FaultSchedule,
     jobs_with_reservation,
@@ -109,6 +109,7 @@ class EagleLayout:
     long_window: int = dataclasses.field(metadata=dict(static=True))
 
 
+@spans.span("simx.build")
 def make_eagle_step(
     cfg: SimxConfig,
     tasks: TaskArrays,
@@ -222,120 +223,126 @@ def make_eagle_step(
         # -- 0. crash-loss rollback + ground truth (completions implicit;
         #       the fault/completion stages ran in the runtime) -------------
         del free  # idleness is re-derived after the sticky launches
-        long_head = s.long_head
-        if faults is not None:
-            # lost long tasks re-enter the central FIFO: roll the head back
-            lt0 = jnp.where(lost_w, s.worker_task, T)
-            long_head = jnp.minimum(
-                long_head, jnp.min(long_pos[lt0]) if NL else long_head
-            )
-        long_here = (worker_finish0 > t) & long_task[s.worker_task]  # bool[W]
+        with jax.named_scope("simx.eagle.rollback"):
+            long_head = s.long_head
+            if faults is not None:
+                # lost long tasks re-enter the central FIFO: roll the head back
+                lt0 = jnp.where(lost_w, s.worker_task, T)
+                long_head = jnp.minimum(
+                    long_head, jnp.min(long_pos[lt0]) if NL else long_head
+                )
+            long_here = (worker_finish0 > t) & long_task[s.worker_task]  # bool[W]
 
         # -- 0b. recycle completed jobs' slots, compact the queues ----------
-        resq, fill = compact_queues(s.resq, task_finish0, tasks.job, t, J)
+        with jax.named_scope("simx.eagle.compact"):
+            resq, fill = compact_queues(s.resq, task_finish0, tasks.job, t, J)
 
         # -- 1. windowed probe insertion with per-edge SSS re-routing -------
-        win_j, win_w, lead, ins, lagged = probe_window_slice(
-            edge_job, edge_worker, s.probe_head, C, job_submit_pad, t
-        )
-        if use_sss:
-            if faults is not None:
-                # SSS also bounces probes off dead workers (the RPC times out)
-                sss_reject = long_here | worker_dead(faults, t)
-            else:
-                sss_reject = long_here
-            wj = jnp.clip(win_j, 0, max(J - 1, 0))
-            rej0 = ins & sss_reject[jnp.clip(win_w, 0, W - 1)]
-            w1 = jnp.where(rej0, (win_w + off1[wj]) % W, win_w)
-            rej1 = rej0 & sss_reject[w1]
-            wfin = jnp.where(rej1, (w1 + off2[wj]) % R, w1)
-            n_rej0 = jnp.sum(rej0, dtype=jnp.int32)
-            n_rej1 = jnp.sum(rej1, dtype=jnp.int32)
-        else:  # no long jobs in the trace: SSS machinery compiles out
-            wfin = win_w
-            n_rej0 = n_rej1 = jnp.int32(0)
-        resq, n_over = insert_probes(resq, fill, wfin, win_j, ins)
-        head = s.probe_head + lead
-        # see the sparrow rule: saturated windows make probe lag observable
-        lag = s.probe_lag + lagged.astype(jnp.int32)
-        probes = s.probes + lead + n_rej0 + n_rej1
-        messages = s.messages + lead + 2 * (n_rej0 + n_rej1)    # reject + resend
+        with jax.named_scope("simx.eagle.insert"):
+            win_j, win_w, lead, ins, lagged = probe_window_slice(
+                edge_job, edge_worker, s.probe_head, C, job_submit_pad, t
+            )
+            if use_sss:
+                if faults is not None:
+                    # SSS also bounces probes off dead workers (the RPC times out)
+                    sss_reject = long_here | worker_dead(faults, t)
+                else:
+                    sss_reject = long_here
+                wj = jnp.clip(win_j, 0, max(J - 1, 0))
+                rej0 = ins & sss_reject[jnp.clip(win_w, 0, W - 1)]
+                w1 = jnp.where(rej0, (win_w + off1[wj]) % W, win_w)
+                rej1 = rej0 & sss_reject[w1]
+                wfin = jnp.where(rej1, (w1 + off2[wj]) % R, w1)
+                n_rej0 = jnp.sum(rej0, dtype=jnp.int32)
+                n_rej1 = jnp.sum(rej1, dtype=jnp.int32)
+            else:  # no long jobs in the trace: SSS machinery compiles out
+                wfin = win_w
+                n_rej0 = n_rej1 = jnp.int32(0)
+            resq, n_over = insert_probes(resq, fill, wfin, win_j, ins)
+            head = s.probe_head + lead
+            # see the sparrow rule: saturated windows make probe lag observable
+            lag = s.probe_lag + lagged.astype(jnp.int32)
+            probes = s.probes + lead + n_rej0 + n_rej1
+            messages = s.messages + lead + 2 * (n_rej0 + n_rej1)    # reject + resend
 
         # -- 2. sticky batch draining: completed workers keep their job -----
-        pend_task = jnp.isinf(task_finish0) & (tasks.submit <= t)
-        pending = (
-            jnp.zeros(J, jnp.int32).at[tasks.job].add(pend_task.astype(jnp.int32))
-        )
-        prev_job = job_pad[s.worker_task]                       # int32[W], J=none
-        pend_prev = jnp.concatenate([pending, jnp.zeros(1, jnp.int32)])[prev_job]
-        sticky_pick = jnp.where(comp & (pend_prev > 0), prev_job, J)
-        launch1, task1 = late_bind(sticky_pick, pend_task, tasks.job, job_start)
-        # the worker already holds the job's spec: no extra hops
-        task_finish, worker_finish, worker_task = apply_launch(
-            launch1, task1, t, task_finish0, worker_finish0, s.worker_task
-        )
+        with jax.named_scope("simx.eagle.drain"):
+            pend_task = jnp.isinf(task_finish0) & (tasks.submit <= t)
+            pending = (
+                jnp.zeros(J, jnp.int32).at[tasks.job].add(pend_task.astype(jnp.int32))
+            )
+            prev_job = job_pad[s.worker_task]                       # int32[W], J=none
+            pend_prev = jnp.concatenate([pending, jnp.zeros(1, jnp.int32)])[prev_job]
+            sticky_pick = jnp.where(comp & (pend_prev > 0), prev_job, J)
+            launch1, task1 = late_bind(sticky_pick, pend_task, tasks.job, job_start)
+            # the worker already holds the job's spec: no extra hops
+            task_finish, worker_finish, worker_task = apply_launch(
+                launch1, task1, t, task_finish0, worker_finish0, s.worker_task
+            )
 
         # -- 3. late binding: idle workers serve their queue heads ----------
-        pend_task = jnp.isinf(task_finish) & (tasks.submit <= t)
-        pending = (
-            jnp.zeros(J + 1, jnp.int32)
-            .at[tasks.job]
-            .add(pend_task.astype(jnp.int32))
-        )
-        idle = worker_finish <= t
-        active = (
-            (resq < J) & (pending[jnp.minimum(resq, J)] > 0) & idle[:, None]
-        )
-        job_pick = queue_head_pick(resq, active, pick_fn, J)    # int32[W]
-        # orphan rescue (see the sparrow rule): a pending short job with no
-        # live reservation anywhere may be served by any idle worker
-        dead = worker_dead(faults, t) if faults is not None else None
-        orphan = (
-            short_job
-            & (edge_end <= head)
-            & (pending[:-1] > 0)
-            & ~jobs_with_reservation(resq, J, dead=dead)
-        )
-        rescue = jnp.min(jnp.where(orphan, j_idx, J))
-        job_pick = jnp.where(idle, jnp.minimum(job_pick, rescue), J)
-        launch2, task2 = late_bind(job_pick, pend_task, tasks.job, job_start)
-        start = t + 3 * cfg.hop  # get-task RPC round trip + launch
-        task_finish, worker_finish, worker_task = apply_launch(
-            launch2, task2, start, task_finish, worker_finish, worker_task
-        )
-        messages = messages + 2 * jnp.sum(launch2, dtype=jnp.int32)
+        with jax.named_scope("simx.eagle.bind"):
+            pend_task = jnp.isinf(task_finish) & (tasks.submit <= t)
+            pending = (
+                jnp.zeros(J + 1, jnp.int32)
+                .at[tasks.job]
+                .add(pend_task.astype(jnp.int32))
+            )
+            idle = worker_finish <= t
+            active = (
+                (resq < J) & (pending[jnp.minimum(resq, J)] > 0) & idle[:, None]
+            )
+            job_pick = queue_head_pick(resq, active, pick_fn, J)    # int32[W]
+            # orphan rescue (see the sparrow rule): a pending short job with no
+            # live reservation anywhere may be served by any idle worker
+            dead = worker_dead(faults, t) if faults is not None else None
+            orphan = (
+                short_job
+                & (edge_end <= head)
+                & (pending[:-1] > 0)
+                & ~jobs_with_reservation(resq, J, dead=dead)
+            )
+            rescue = jnp.min(jnp.where(orphan, j_idx, J))
+            job_pick = jnp.where(idle, jnp.minimum(job_pick, rescue), J)
+            launch2, task2 = late_bind(job_pick, pend_task, tasks.job, job_start)
+            start = t + 3 * cfg.hop  # get-task RPC round trip + launch
+            task_finish, worker_finish, worker_task = apply_launch(
+                launch2, task2, start, task_finish, worker_finish, worker_task
+            )
+            messages = messages + 2 * jnp.sum(launch2, dtype=jnp.int32)
 
-        n_launch = (
-            jnp.sum(launch1, dtype=jnp.int32) + jnp.sum(launch2, dtype=jnp.int32)
-        )
+            n_launch = (
+                jnp.sum(launch1, dtype=jnp.int32) + jnp.sum(launch2, dtype=jnp.int32)
+            )
 
         # -- 4. central scheduler: queued long window -> free long partition
-        if use_central:
-            wtask = jax.lax.dynamic_slice(long_fifo, (long_head,), (CL,))
-            wsub = submit_pad[jnp.minimum(wtask, T)]
-            wsub = jnp.where(wtask >= T, jnp.inf, wsub)
-            fpad = rt.finish_pad(task_finish)
-            launched = rt.window_launched(fpad, wtask, T)       # bool[CL]
-            queued = ~launched & (wsub <= t)
-            nq = jnp.sum(queued, dtype=jnp.int32)
-            # sticky launches punch holes mid-window: sort queued positions
-            # ahead of the CL sentinels to recover FIFO order
-            fifo = rt.sorted_fifo(queued, CL)
-            avail = ((worker_finish <= t) & (w_row >= R))[None, :]
-            ranks = match_fn(avail, nq[None])[0]                # int32[W]
-            sel_task = rt.select_from_window(ranks, fifo, wtask, T)
-            launch3 = sel_task < T
-            task_finish, worker_finish, worker_task = apply_launch(
-                launch3, sel_task, start, task_finish, worker_finish, worker_task
-            )
-            messages = messages + jnp.sum(launch3, dtype=jnp.int32)
-            n_launch = n_launch + jnp.sum(launch3, dtype=jnp.int32)
-            # advance the head past the launched prefix
-            fpad2 = rt.finish_pad(task_finish)
-            launched2 = rt.window_launched(fpad2, wtask, T)
-            long_head = jnp.minimum(
-                long_head + rt.launched_lead(launched2), nl_clamp
-            )
+        with jax.named_scope("simx.eagle.central"):
+            if use_central:
+                wtask = jax.lax.dynamic_slice(long_fifo, (long_head,), (CL,))
+                wsub = submit_pad[jnp.minimum(wtask, T)]
+                wsub = jnp.where(wtask >= T, jnp.inf, wsub)
+                fpad = rt.finish_pad(task_finish)
+                launched = rt.window_launched(fpad, wtask, T)       # bool[CL]
+                queued = ~launched & (wsub <= t)
+                nq = jnp.sum(queued, dtype=jnp.int32)
+                # sticky launches punch holes mid-window: sort queued positions
+                # ahead of the CL sentinels to recover FIFO order
+                fifo = rt.sorted_fifo(queued, CL)
+                avail = ((worker_finish <= t) & (w_row >= R))[None, :]
+                ranks = match_fn(avail, nq[None])[0]                # int32[W]
+                sel_task = rt.select_from_window(ranks, fifo, wtask, T)
+                launch3 = sel_task < T
+                task_finish, worker_finish, worker_task = apply_launch(
+                    launch3, sel_task, start, task_finish, worker_finish, worker_task
+                )
+                messages = messages + jnp.sum(launch3, dtype=jnp.int32)
+                n_launch = n_launch + jnp.sum(launch3, dtype=jnp.int32)
+                # advance the head past the launched prefix
+                fpad2 = rt.finish_pad(task_finish)
+                launched2 = rt.window_launched(fpad2, wtask, T)
+                long_head = jnp.minimum(
+                    long_head + rt.launched_lead(launched2), nl_clamp
+                )
 
         upd = dict(
             task_finish=task_finish,
@@ -350,37 +357,39 @@ def make_eagle_step(
             probes=probes,
         )
         if telemetry:
-            upd["telemetry"] = dict(
-                launches=n_launch, sss_rejections=n_rej0 + n_rej1
-            )
+            with jax.named_scope("simx.telemetry"):
+                upd["telemetry"] = dict(
+                    launches=n_launch, sss_rejections=n_rej0 + n_rej1
+                )
         if provenance:
-            # attempt = a scheduler acted on the task's job this round:
-            # short-path probes inserted (or orphan-rescued), or the long
-            # task sat in the central scheduler's queued match window.
-            # Sticky launches are or-ed in by the runtime's launch latch.
-            # authority = the job's home distributed scheduler for short
-            # jobs (job % num_gms), entity ``num_gms`` for the central
-            # long-path scheduler.
-            att_j = (
-                jnp.zeros(J + 1, jnp.bool_)
-                .at[jnp.where(ins, win_j, J)]
-                .set(True, mode="drop")
-            )
-            att_j = att_j.at[:-1].max(orphan)
-            attempt = att_j[:-1][tasks.job]
-            if use_central:
-                attempt = attempt | (
-                    jnp.zeros(T, jnp.bool_)
-                    .at[jnp.where(queued, wtask, T)]
+            with jax.named_scope("simx.provenance"):
+                # attempt = a scheduler acted on the task's job this round:
+                # short-path probes inserted (or orphan-rescued), or the long
+                # task sat in the central scheduler's queued match window.
+                # Sticky launches are or-ed in by the runtime's launch latch.
+                # authority = the job's home distributed scheduler for short
+                # jobs (job % num_gms), entity ``num_gms`` for the central
+                # long-path scheduler.
+                att_j = (
+                    jnp.zeros(J + 1, jnp.bool_)
+                    .at[jnp.where(ins, win_j, J)]
                     .set(True, mode="drop")
                 )
-            aj = job_pad[jnp.minimum(worker_task, T)]
-            authority = jnp.where(
-                long_task[jnp.minimum(worker_task, T)],
-                jnp.int32(cfg.num_gms),
-                (jnp.minimum(aj, J - 1) % cfg.num_gms).astype(jnp.int32),
-            )
-            upd["provenance"] = dict(attempt=attempt, authority=authority)
+                att_j = att_j.at[:-1].max(orphan)
+                attempt = att_j[:-1][tasks.job]
+                if use_central:
+                    attempt = attempt | (
+                        jnp.zeros(T, jnp.bool_)
+                        .at[jnp.where(queued, wtask, T)]
+                        .set(True, mode="drop")
+                    )
+                aj = job_pad[jnp.minimum(worker_task, T)]
+                authority = jnp.where(
+                    long_task[jnp.minimum(worker_task, T)],
+                    jnp.int32(cfg.num_gms),
+                    (jnp.minimum(aj, J - 1) % cfg.num_gms).astype(jnp.int32),
+                )
+                upd["provenance"] = dict(attempt=attempt, authority=authority)
         return upd
 
     return rt.compose_step(

@@ -148,7 +148,7 @@ import numpy as np
 from repro.core.base import LONG_JOB_THRESHOLD
 from repro.core.megha import grid_workers
 from repro.core.metrics import JobRecord, RunMetrics, TaskRecord, classify_long
-from repro.simx import runtime
+from repro.simx import runtime, spans
 from repro.simx.faults import FaultPlan, FaultSchedule, is_empty
 from repro.simx.provenance import Provenance, init_provenance
 
@@ -199,14 +199,23 @@ def make_chunk_runner(
     only the returned state is valid after the call (the ``simx_donation``
     bench row reports the measured wall/peak-memory deltas).  Off by
     default because callers that re-read a prior state (the doneprobe
-    bench keeps every chunk's state alive) would see garbage."""
+    bench keeps every chunk's state alive) would see garbage.
 
-    def run(c):
+    The runner is a ``spans.Program`` named ``simx_chunk`` (its compile
+    events carry that name, and its optimized HLO stays readable for
+    op-to-stage attribution); the done reduction is the ``simx.done``
+    scope."""
+
+    def simx_chunk(c):
         c = scan_rounds(step, c, chunk)
         s = runtime.carry_state(c)
-        return c, jnp.all(s.task_finish <= s.t)
+        with jax.named_scope("simx.done"):
+            done = jnp.all(s.task_finish <= s.t)
+        return c, done
 
-    return jax.jit(run, donate_argnums=(0,) if donate else ())
+    return spans.Program(
+        "simx_chunk", jax.jit(simx_chunk, donate_argnums=(0,) if donate else ())
+    )
 
 
 @partial(jax.jit, static_argnums=(0, 2))

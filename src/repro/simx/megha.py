@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.simx import runtime as rt
+from repro.simx import runtime as rt, spans
 from repro.simx.faults import (
     FaultSchedule,
     gm_adoption,
@@ -54,6 +54,7 @@ from repro.simx.state import (
 )
 
 
+@spans.span("simx.build")
 def gm_orders(key: jax.Array, cfg: SimxConfig) -> jax.Array:
     """int32[G, W] per-GM priority permutations: own partitions (shuffled)
     first, then external partitions (shuffled), mirroring
@@ -97,6 +98,7 @@ class MeghaLayout:
     window: int = dataclasses.field(metadata=dict(static=True))
 
 
+@spans.span("simx.build")
 def make_megha_step(
     cfg: SimxConfig,
     tasks: TaskArrays,
@@ -215,213 +217,226 @@ def make_megha_step(
 
     def dispatch(s, t, task_finish0, worker_finish0, truth, comp, lost_w):
         # -- 0. crash-loss rollback (fault stage ran in the runtime) --------
-        head0 = s.head
-        if faults is not None:
-            # re-enqueue lost tasks: roll each GM's FIFO head back to the
-            # earliest lost position (re-examined over the coming rounds)
-            lt0 = jnp.where(lost_w, s.worker_task, T)
-            head0 = head0.at[task_gm_pad[lt0]].min(
-                task_pos_pad[lt0], mode="drop"
-            )
+        with jax.named_scope("simx.megha.rollback"):
+            head0 = s.head
+            if faults is not None:
+                # re-enqueue lost tasks: roll each GM's FIFO head back to the
+                # earliest lost position (re-examined over the coming rounds)
+                lt0 = jnp.where(lost_w, s.worker_task, T)
+                head0 = head0.at[task_gm_pad[lt0]].min(
+                    task_pos_pad[lt0], mode="drop"
+                )
 
         # -- 1. completions (truth/comp = the runtime's completion stage) ---
-        regain = ((s.worker_gm[None, :] == g_col) & (comp & ~s.worker_borrowed))
-        view = s.view | regain
-        messages = s.messages + jnp.sum(comp, dtype=jnp.int32)  # LM -> GM
+        with jax.named_scope("simx.megha.views"):
+            regain = ((s.worker_gm[None, :] == g_col) & (comp & ~s.worker_borrowed))
+            view = s.view | regain
+            messages = s.messages + jnp.sum(comp, dtype=jnp.int32)  # LM -> GM
 
-        # -- 2. heartbeat (+ GM down windows / recovery resets) -------------
-        if faults is None:
-            do_hb = (s.rnd % hb) == (hb - 1)
-            view = jnp.where(do_hb, truth[None, :], view)
-            messages = messages + jnp.where(do_hb, G * L, 0).astype(jnp.int32)
-            adopt = None
-        else:
-            hb_eff = hb + faults.hb_extra_rounds       # delay perturbation
-            do_hb = (s.rnd % hb_eff) == (hb_eff - 1)
-            adopt, row_active, n_live = gm_adoption(
-                gm_down_mask(faults, t), s.rnd
-            )
-            view = jnp.where(do_hb, truth[None, :], view)
-            messages = messages + jnp.where(do_hb, n_live * L, 0).astype(
-                jnp.int32
-            )
-            # §3.5 recovery: a returning GM rebuilds its view from LM truth
-            rec = gm_recovered_now(faults, t, cfg.dt)
-            view = jnp.where(rec[:, None], truth[None, :], view)
-            messages = messages + L * jnp.sum(rec, dtype=jnp.int32)
+            # -- 2. heartbeat (+ GM down windows / recovery resets) ---------
+            if faults is None:
+                do_hb = (s.rnd % hb) == (hb - 1)
+                view = jnp.where(do_hb, truth[None, :], view)
+                messages = messages + jnp.where(do_hb, G * L, 0).astype(jnp.int32)
+                adopt = None
+            else:
+                hb_eff = hb + faults.hb_extra_rounds       # delay perturbation
+                do_hb = (s.rnd % hb_eff) == (hb_eff - 1)
+                adopt, row_active, n_live = gm_adoption(
+                    gm_down_mask(faults, t), s.rnd
+                )
+                view = jnp.where(do_hb, truth[None, :], view)
+                messages = messages + jnp.where(do_hb, n_live * L, 0).astype(
+                    jnp.int32
+                )
+                # §3.5 recovery: a returning GM rebuilds its view from LM truth
+                rec = gm_recovered_now(faults, t, cfg.dt)
+                view = jnp.where(rec[:, None], truth[None, :], view)
+                messages = messages + L * jnp.sum(rec, dtype=jnp.int32)
 
         # -- 3. internal match (FIFO windows, [G, W/G] arrays) --------------
-        wtask = rt.slice_rows(gm_tasks, head0, C)                 # int32[G,C]
-        wsubmit = rt.slice_rows(submit_c, head0, C)               # float32[G,C]
-        fpad = rt.finish_pad(task_finish0)
-        launched_w = rt.window_launched(fpad, wtask, T)           # bool[G,C]
-        queued_w = ~launched_w & (wsubmit <= t)                   # bool[G,C]
-        if faults is not None:
-            queued_w = queued_w & row_active[:, None]  # frozen when no GM live
-        nq = jnp.sum(queued_w, axis=1, dtype=jnp.int32)           # int32[G]
-        fifo = rt.sorted_fifo(queued_w, C)                        # int32[G,C]
-        view_eff = view if adopt is None else view[adopt]
-        avail_int = view_eff[g_col, int_ord]                      # bool[G,wi]
-        ranks_i = match_fn(avail_int, nq)                         # int32[G,wi]
-        sel_pos = jnp.take_along_axis(
-            fifo, jnp.clip(ranks_i, 0, C - 1), axis=1
-        )
-        sel_task_i = jnp.where(
-            ranks_i >= 0,
-            jnp.take_along_axis(wtask, jnp.clip(sel_pos, 0, C - 1), axis=1),
-            -1,
-        )                                                         # int32[G,wi]
-        proposed_i = sel_task_i >= 0
-        truth_int = truth[int_ord]                                # bool[G,wi]
-        launch_i = proposed_i & truth_int
-        invalid_i = proposed_i & ~truth_int
-        # flat (g, i) -> worker coordinates via the static inverse perm
-        launch_w = launch_i.reshape(-1)[inv_int]                  # bool[W]
-        task_w = jnp.where(launch_w, sel_task_i.reshape(-1)[inv_int], T)
-        (task_finish, worker_finish, worker_task, worker_gm,
-         worker_borrowed) = launch_updates(
-            t, launch_w, task_w, part_gm,
-            task_finish0, worker_finish0, s.worker_task,
-            s.worker_gm, s.worker_borrowed,
-        )
-        truth = truth & ~launch_w
-        # the proposing GM marks every proposed internal worker busy in its
-        # own view (popped from the free pool when the batch was built)
-        proposed_own = proposed_i.reshape(-1)[inv_int]            # bool[W]
-        view = view & ~(proposed_own[None, :] & (part_gm[None, :] == g_col))
-        inconsistencies = s.inconsistencies + jnp.sum(invalid_i, dtype=jnp.int32)
-        inval_gl = (invalid_i[:, :, None] & (lm_int[:, :, None] == l_row)).any(axis=1)
-        view = piggyback(view, truth, inval_gl, adopt)
-        batch_gl = (proposed_i[:, :, None] & (lm_int[:, :, None] == l_row)).any(axis=1)
-        messages = messages + 2 * jnp.sum(batch_gl, dtype=jnp.int32)
-        if telemetry:
-            # per-round counters: launches + piggybacked [GM, LM] view
-            # repairs (§3.4.1), accumulated through the borrow cond's carry
-            tel_launch = jnp.sum(launch_w, dtype=jnp.int32)
-            tel_repair = jnp.sum(inval_gl, dtype=jnp.int32)
-        if provenance:
-            # attempt = every queued task in a GM window (ranked this
-            # round); stale = per-task invalid-proposal increments (the
-            # §3.4 inconsistencies), borrow-phase hits accumulated through
-            # the cond carry like the telemetry scalars
-            prov_attempt = (
-                jnp.zeros(T, jnp.bool_)
-                .at[jnp.where(queued_w, wtask, T)]
-                .set(True, mode="drop")
+        with jax.named_scope("simx.megha.internal"):
+            wtask = rt.slice_rows(gm_tasks, head0, C)                 # int32[G,C]
+            wsubmit = rt.slice_rows(submit_c, head0, C)               # float32[G,C]
+            fpad = rt.finish_pad(task_finish0)
+            launched_w = rt.window_launched(fpad, wtask, T)           # bool[G,C]
+            queued_w = ~launched_w & (wsubmit <= t)                   # bool[G,C]
+            if faults is not None:
+                queued_w = queued_w & row_active[:, None]  # frozen when no GM live
+            nq = jnp.sum(queued_w, axis=1, dtype=jnp.int32)           # int32[G]
+            fifo = rt.sorted_fifo(queued_w, C)                        # int32[G,C]
+            view_eff = view if adopt is None else view[adopt]
+            avail_int = view_eff[g_col, int_ord]                      # bool[G,wi]
+            ranks_i = match_fn(avail_int, nq)                         # int32[G,wi]
+            sel_pos = jnp.take_along_axis(
+                fifo, jnp.clip(ranks_i, 0, C - 1), axis=1
             )
-            stale_inc = (
-                jnp.zeros(T, jnp.int32)
-                .at[jnp.where(invalid_i, sel_task_i, T)]
-                .add(1, mode="drop")
+            sel_task_i = jnp.where(
+                ranks_i >= 0,
+                jnp.take_along_axis(wtask, jnp.clip(sel_pos, 0, C - 1), axis=1),
+                -1,
+            )                                                         # int32[G,wi]
+            proposed_i = sel_task_i >= 0
+            truth_int = truth[int_ord]                                # bool[G,wi]
+            launch_i = proposed_i & truth_int
+            invalid_i = proposed_i & ~truth_int
+            # flat (g, i) -> worker coordinates via the static inverse perm
+            launch_w = launch_i.reshape(-1)[inv_int]                  # bool[W]
+            task_w = jnp.where(launch_w, sel_task_i.reshape(-1)[inv_int], T)
+            (task_finish, worker_finish, worker_task, worker_gm,
+             worker_borrowed) = launch_updates(
+                t, launch_w, task_w, part_gm,
+                task_finish0, worker_finish0, s.worker_task,
+                s.worker_gm, s.worker_borrowed,
             )
+            truth = truth & ~launch_w
+            # the proposing GM marks every proposed internal worker busy in its
+            # own view (popped from the free pool when the batch was built)
+            proposed_own = proposed_i.reshape(-1)[inv_int]            # bool[W]
+            view = view & ~(proposed_own[None, :] & (part_gm[None, :] == g_col))
+            inconsistencies = s.inconsistencies + jnp.sum(invalid_i, dtype=jnp.int32)
+            inval_gl = (
+                invalid_i[:, :, None] & (lm_int[:, :, None] == l_row)
+            ).any(axis=1)
+            view = piggyback(view, truth, inval_gl, adopt)
+            batch_gl = (
+                proposed_i[:, :, None] & (lm_int[:, :, None] == l_row)
+            ).any(axis=1)
+            messages = messages + 2 * jnp.sum(batch_gl, dtype=jnp.int32)
+            if telemetry:
+                with jax.named_scope("simx.telemetry"):
+                    # per-round counters: launches + piggybacked [GM, LM] view
+                    # repairs (§3.4.1), accumulated through the borrow cond's carry
+                    tel_launch = jnp.sum(launch_w, dtype=jnp.int32)
+                    tel_repair = jnp.sum(inval_gl, dtype=jnp.int32)
+            if provenance:
+                with jax.named_scope("simx.provenance"):
+                    # attempt = every queued task in a GM window (ranked this
+                    # round); stale = per-task invalid-proposal increments (the
+                    # §3.4 inconsistencies), borrow-phase hits accumulated through
+                    # the cond carry like the telemetry scalars
+                    prov_attempt = (
+                        jnp.zeros(T, jnp.bool_)
+                        .at[jnp.where(queued_w, wtask, T)]
+                        .set(True, mode="drop")
+                    )
+                    stale_inc = (
+                        jnp.zeros(T, jnp.int32)
+                        .at[jnp.where(invalid_i, sel_task_i, T)]
+                        .add(1, mode="drop")
+                    )
 
         # -- 4. borrow match (full [G, W] pass, only when queues outrun the
         #       internal views) --------------------------------------------
-        placed_i = jnp.sum(proposed_i, axis=1, dtype=jnp.int32)
-        need_borrow = jnp.any(nq > placed_i)
+        with jax.named_scope("simx.megha.borrow"):
+            placed_i = jnp.sum(proposed_i, axis=1, dtype=jnp.int32)
+            need_borrow = jnp.any(nq > placed_i)
 
-        def borrow(args):
-            (view, truth, task_finish, worker_finish, worker_task, worker_gm,
-             worker_borrowed, inconsistencies, repartitions, messages) = args[:10]
-            fpad2 = rt.finish_pad(task_finish)
-            launched2 = rt.window_launched(fpad2, wtask, T)
-            queued2 = ~launched2 & (wsubmit <= t)
-            if faults is not None:
-                queued2 = queued2 & row_active[:, None]
-            nq2 = jnp.sum(queued2, axis=1, dtype=jnp.int32)
-            fifo2 = rt.sorted_fifo(queued2, C)
-            view_b = view if adopt is None else view[adopt]
-            avail_ord = jnp.take_along_axis(view_b, orders, axis=1)  # bool[G,W]
-            ranks = match_fn(avail_ord, nq2)                       # int32[G,W]
-            sel_pos2 = jnp.take_along_axis(
-                fifo2, jnp.clip(ranks, 0, C - 1), axis=1
-            )
-            sel_task = jnp.where(
-                ranks >= 0,
-                jnp.take_along_axis(wtask, jnp.clip(sel_pos2, 0, C - 1), axis=1),
-                -1,
-            )
-            # ordered positions -> worker coordinates (inverse gather)
-            prop = jnp.take_along_axis(sel_task, inv_orders, axis=1)
-            proposed = prop >= 0
-            repartitions = repartitions + jnp.sum(
-                proposed & (part_gm[None, :] != g_col), dtype=jnp.int32
-            )
-            # simultaneous claims: per-round rotating GM priority, one
-            # min-reduction over (priority, gm) packed into a single int
-            pri = (g_col + s.rnd) % G
-            enc = jnp.where(
-                proposed, jnp.broadcast_to(pri * G, (G, W)) + g_col, G * G
-            )
-            win_enc = jnp.min(enc, axis=0)                         # int32[W]
-            any_prop = win_enc < G * G
-            win_g = jnp.where(any_prop, win_enc % G, 0)
-            launch = any_prop & truth                              # bool[W]
-            win_task = jnp.where(launch, prop[win_g, w_row], T)
-            (task_finish, worker_finish, worker_task, worker_gm,
-             worker_borrowed) = launch_updates(
-                t, launch, win_task, win_g,
-                task_finish, worker_finish, worker_task,
-                worker_gm, worker_borrowed,
-            )
-            truth = truth & ~launch
-            view = view & ~proposed
-            launched_by_g = launch[None, :] & (g_col == win_g[None, :])
-            invalid = proposed & ~launched_by_g                    # bool[G,W]
-            inconsistencies = inconsistencies + jnp.sum(invalid, dtype=jnp.int32)
-            inval2_gl = invalid.reshape(G, L, wpl).any(axis=2)
-            view = piggyback(view, truth, inval2_gl, adopt)
-            batch2 = proposed.reshape(G, L, wpl).any(axis=2)
-            messages = messages + 2 * jnp.sum(batch2, dtype=jnp.int32)
-            out = (view, truth, task_finish, worker_finish, worker_task,
-                   worker_gm, worker_borrowed, inconsistencies, repartitions,
-                   messages)
+            def borrow(args):
+                (view, truth, task_finish, worker_finish, worker_task, worker_gm,
+                 worker_borrowed, inconsistencies, repartitions, messages) = args[:10]
+                fpad2 = rt.finish_pad(task_finish)
+                launched2 = rt.window_launched(fpad2, wtask, T)
+                queued2 = ~launched2 & (wsubmit <= t)
+                if faults is not None:
+                    queued2 = queued2 & row_active[:, None]
+                nq2 = jnp.sum(queued2, axis=1, dtype=jnp.int32)
+                fifo2 = rt.sorted_fifo(queued2, C)
+                view_b = view if adopt is None else view[adopt]
+                avail_ord = jnp.take_along_axis(view_b, orders, axis=1)  # bool[G,W]
+                ranks = match_fn(avail_ord, nq2)                       # int32[G,W]
+                sel_pos2 = jnp.take_along_axis(
+                    fifo2, jnp.clip(ranks, 0, C - 1), axis=1
+                )
+                sel_task = jnp.where(
+                    ranks >= 0,
+                    jnp.take_along_axis(wtask, jnp.clip(sel_pos2, 0, C - 1), axis=1),
+                    -1,
+                )
+                # ordered positions -> worker coordinates (inverse gather)
+                prop = jnp.take_along_axis(sel_task, inv_orders, axis=1)
+                proposed = prop >= 0
+                repartitions = repartitions + jnp.sum(
+                    proposed & (part_gm[None, :] != g_col), dtype=jnp.int32
+                )
+                # simultaneous claims: per-round rotating GM priority, one
+                # min-reduction over (priority, gm) packed into a single int
+                pri = (g_col + s.rnd) % G
+                enc = jnp.where(
+                    proposed, jnp.broadcast_to(pri * G, (G, W)) + g_col, G * G
+                )
+                win_enc = jnp.min(enc, axis=0)                         # int32[W]
+                any_prop = win_enc < G * G
+                win_g = jnp.where(any_prop, win_enc % G, 0)
+                launch = any_prop & truth                              # bool[W]
+                win_task = jnp.where(launch, prop[win_g, w_row], T)
+                (task_finish, worker_finish, worker_task, worker_gm,
+                 worker_borrowed) = launch_updates(
+                    t, launch, win_task, win_g,
+                    task_finish, worker_finish, worker_task,
+                    worker_gm, worker_borrowed,
+                )
+                truth = truth & ~launch
+                view = view & ~proposed
+                launched_by_g = launch[None, :] & (g_col == win_g[None, :])
+                invalid = proposed & ~launched_by_g                    # bool[G,W]
+                inconsistencies = inconsistencies + jnp.sum(invalid, dtype=jnp.int32)
+                inval2_gl = invalid.reshape(G, L, wpl).any(axis=2)
+                view = piggyback(view, truth, inval2_gl, adopt)
+                batch2 = proposed.reshape(G, L, wpl).any(axis=2)
+                messages = messages + 2 * jnp.sum(batch2, dtype=jnp.int32)
+                out = (view, truth, task_finish, worker_finish, worker_task,
+                       worker_gm, worker_borrowed, inconsistencies, repartitions,
+                       messages)
+                if telemetry:
+                    with jax.named_scope("simx.telemetry"):
+                        out = out + (
+                            args[10] + jnp.sum(launch, dtype=jnp.int32),
+                            args[11] + jnp.sum(inval2_gl, dtype=jnp.int32),
+                        )
+                if provenance:
+                    with jax.named_scope("simx.provenance"):
+                        out = out + (
+                            args[-1]
+                            + jnp.zeros(T, jnp.int32)
+                            .at[jnp.where(invalid, prop, T)]
+                            .add(1, mode="drop"),
+                        )
+                return out
+
+            carry = (view, truth, task_finish, worker_finish, worker_task,
+                     worker_gm, worker_borrowed, inconsistencies, s.repartitions,
+                     messages)
             if telemetry:
-                out = out + (
-                    args[10] + jnp.sum(launch, dtype=jnp.int32),
-                    args[11] + jnp.sum(inval2_gl, dtype=jnp.int32),
-                )
+                carry = carry + (tel_launch, tel_repair)
             if provenance:
-                out = out + (
-                    args[-1]
-                    + jnp.zeros(T, jnp.int32)
-                    .at[jnp.where(invalid, prop, T)]
-                    .add(1, mode="drop"),
-                )
-            return out
-
-        carry = (view, truth, task_finish, worker_finish, worker_task,
-                 worker_gm, worker_borrowed, inconsistencies, s.repartitions,
-                 messages)
-        if telemetry:
-            carry = carry + (tel_launch, tel_repair)
-        if provenance:
-            carry = carry + (stale_inc,)
-        carry = jax.lax.cond(need_borrow, borrow, lambda a: a, carry)
-        (view, truth, task_finish, worker_finish, worker_task, worker_gm,
-         worker_borrowed, inconsistencies, repartitions, messages) = carry[:10]
-        if telemetry:
-            tel_launch, tel_repair = carry[10], carry[11]
-        if provenance:
-            stale_inc = carry[-1]
+                carry = carry + (stale_inc,)
+            carry = jax.lax.cond(need_borrow, borrow, lambda a: a, carry)
+            (view, truth, task_finish, worker_finish, worker_task, worker_gm,
+             worker_borrowed, inconsistencies, repartitions, messages) = carry[:10]
+            if telemetry:
+                tel_launch, tel_repair = carry[10], carry[11]
+            if provenance:
+                stale_inc = carry[-1]
 
         # -- 5. advance each GM's FIFO head past its launched prefix --------
-        fpad3 = rt.finish_pad(task_finish)
-        launched3 = rt.window_launched(fpad3, wtask, T)            # bool[G,C]
-        head = jnp.minimum(head0 + rt.launched_lead(launched3), gm_len)
+        with jax.named_scope("simx.megha.head"):
+            fpad3 = rt.finish_pad(task_finish)
+            launched3 = rt.window_launched(fpad3, wtask, T)            # bool[G,C]
+            head = jnp.minimum(head0 + rt.launched_lead(launched3), gm_len)
 
-        upd = dict(
-            task_finish=task_finish,
-            head=head,
-            worker_finish=worker_finish,
-            worker_task=worker_task,
-            worker_gm=worker_gm,
-            worker_borrowed=worker_borrowed,
-            view=view,
-            inconsistencies=inconsistencies,
-            repartitions=repartitions,
-            messages=messages,
-        )
+            upd = dict(
+                task_finish=task_finish,
+                head=head,
+                worker_finish=worker_finish,
+                worker_task=worker_task,
+                worker_gm=worker_gm,
+                worker_borrowed=worker_borrowed,
+                view=view,
+                inconsistencies=inconsistencies,
+                repartitions=repartitions,
+                messages=messages,
+            )
         if telemetry:
             upd["telemetry"] = dict(
                 launches=tel_launch, view_repairs=tel_repair

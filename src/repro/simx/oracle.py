@@ -34,12 +34,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.simx import runtime as rt
+from repro.simx import runtime as rt, spans
 from repro.simx.faults import FaultSchedule
 from repro.simx.runtime import MatchFn, default_match_fn
 from repro.simx.state import OracleState, SimxConfig, TaskArrays, init_oracle_state
 
 
+@spans.span("simx.build")
 def make_oracle_step(
     cfg: SimxConfig,
     tasks: TaskArrays,
@@ -73,58 +74,64 @@ def make_oracle_step(
     def dispatch(s, t, task_finish0, worker_finish0, free, comp, lost_w):
         del comp
         # -- 0. crash-loss rollback: a lost task's id is its FIFO position -
-        head0 = s.head
-        if faults is not None:
-            lost_t = jnp.where(lost_w, s.worker_task, T)
-            head0 = jnp.minimum(head0, jnp.min(lost_t))
+        with jax.named_scope("simx.oracle.rollback"):
+            head0 = s.head
+            if faults is not None:
+                lost_t = jnp.where(lost_w, s.worker_task, T)
+                head0 = jnp.minimum(head0, jnp.min(lost_t))
 
         # -- 1. queued window (holes possible after a rollback) -------------
-        wtask = jax.lax.dynamic_slice(fifo, (head0,), (C,))
-        wsub = jnp.where(wtask >= T, jnp.inf, submit_pad[jnp.minimum(wtask, T)])
-        fpad = rt.finish_pad(task_finish0)
-        launched = rt.window_launched(fpad, wtask, T)             # bool[C]
-        queued = ~launched & (wsub <= t)
-        nq = jnp.sum(queued, dtype=jnp.int32)
-        fifo_pos = rt.sorted_fifo(queued, C)
+        with jax.named_scope("simx.oracle.window"):
+            wtask = jax.lax.dynamic_slice(fifo, (head0,), (C,))
+            wsub = jnp.where(wtask >= T, jnp.inf, submit_pad[jnp.minimum(wtask, T)])
+            fpad = rt.finish_pad(task_finish0)
+            launched = rt.window_launched(fpad, wtask, T)             # bool[C]
+            queued = ~launched & (wsub <= t)
+            nq = jnp.sum(queued, dtype=jnp.int32)
+            fifo_pos = rt.sorted_fifo(queued, C)
 
         # -- 2. perfect match: FIFO ranks onto actually-free workers --------
-        ranks = match_fn(free[None, :], nq[None])[0]              # int32[W]
-        sel_task = rt.select_from_window(ranks, fifo_pos, wtask, T)
-        launch = sel_task < T
+        with jax.named_scope("simx.oracle.match"):
+            ranks = match_fn(free[None, :], nq[None])[0]              # int32[W]
+            sel_task = rt.select_from_window(ranks, fifo_pos, wtask, T)
+            launch = sel_task < T
 
         # -- 3. launch: same hop costs as the real schedulers ---------------
-        task_finish, worker_finish, worker_task = rt.apply_launch(
-            launch, sel_task, t + 3 * cfg.hop, dur_pad,
-            task_finish0, worker_finish0, s.worker_task, T,
-        )
-        messages = s.messages + jnp.sum(launch, dtype=jnp.int32)
+        with jax.named_scope("simx.oracle.launch"):
+            task_finish, worker_finish, worker_task = rt.apply_launch(
+                launch, sel_task, t + 3 * cfg.hop, dur_pad,
+                task_finish0, worker_finish0, s.worker_task, T,
+            )
+            messages = s.messages + jnp.sum(launch, dtype=jnp.int32)
 
-        # -- 4. advance the head past the launched prefix -------------------
-        fpad2 = rt.finish_pad(task_finish)
-        launched2 = rt.window_launched(fpad2, wtask, T)
-        head = jnp.minimum(head0 + rt.launched_lead(launched2), T)
+            # -- 4. advance the head past the launched prefix ---------------
+            fpad2 = rt.finish_pad(task_finish)
+            launched2 = rt.window_launched(fpad2, wtask, T)
+            head = jnp.minimum(head0 + rt.launched_lead(launched2), T)
 
-        upd = dict(
-            task_finish=task_finish,
-            worker_finish=worker_finish,
-            worker_task=worker_task,
-            head=head,
-            messages=messages,
-        )
+            upd = dict(
+                task_finish=task_finish,
+                worker_finish=worker_finish,
+                worker_task=worker_task,
+                head=head,
+                messages=messages,
+            )
         if telemetry:
-            upd["telemetry"] = dict(launches=jnp.sum(launch, dtype=jnp.int32))
+            with jax.named_scope("simx.telemetry"):
+                upd["telemetry"] = dict(launches=jnp.sum(launch, dtype=jnp.int32))
         if provenance:
-            # attempt = the whole queued window (every queued task in it
-            # was ranked against the free set); authority = the single
-            # omniscient scheduler, entity 0
-            attempt = (
-                jnp.zeros(T, jnp.bool_)
-                .at[jnp.where(queued, wtask, T)]
-                .set(True, mode="drop")
-            )
-            upd["provenance"] = dict(
-                attempt=attempt, authority=jnp.zeros(W, jnp.int32)
-            )
+            with jax.named_scope("simx.provenance"):
+                # attempt = the whole queued window (every queued task in it
+                # was ranked against the free set); authority = the single
+                # omniscient scheduler, entity 0
+                attempt = (
+                    jnp.zeros(T, jnp.bool_)
+                    .at[jnp.where(queued, wtask, T)]
+                    .set(True, mode="drop")
+                )
+                upd["provenance"] = dict(
+                    attempt=attempt, authority=jnp.zeros(W, jnp.int32)
+                )
         return upd
 
     return rt.compose_step(

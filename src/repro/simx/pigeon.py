@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.simx import runtime as rt
+from repro.simx import runtime as rt, spans
 from repro.simx.faults import FaultSchedule
 from repro.simx.runtime import MatchFn, default_match_fn
 from repro.simx.state import (
@@ -87,6 +87,7 @@ class PigeonLayout:
     len_low: jax.Array = spec("int32[NG]")
 
 
+@spans.span("simx.build")
 def make_pigeon_step(
     cfg: SimxConfig,
     tasks: TaskArrays,
@@ -199,175 +200,183 @@ def make_pigeon_step(
     def dispatch(s, t, task_finish0, worker_finish0, free_w, comp, lost_w):
         # -- 0. crash-loss rollback (fault stage ran in the runtime) --------
         del comp  # completions stay implicit in the group capacity gather
-        high_head0, low_head0 = s.high_head, s.low_head
-        if faults is not None:
-            # re-enqueue lost tasks: roll the owning group's class FIFO back
-            lt0 = jnp.where(lost_w, s.worker_task, T)
-            g0, p0, hi0 = grp_pad[lt0], task_pos_pad[lt0], high_pad[lt0]
-            high_head0 = high_head0.at[jnp.where(hi0, g0, NG)].min(
-                p0, mode="drop"
-            )
-            low_head0 = low_head0.at[jnp.where(hi0, NG, g0)].min(
-                p0, mode="drop"
-            )
+        with jax.named_scope("simx.pigeon.rollback"):
+            high_head0, low_head0 = s.high_head, s.low_head
+            if faults is not None:
+                # re-enqueue lost tasks: roll the owning group's class FIFO back
+                lt0 = jnp.where(lost_w, s.worker_task, T)
+                g0, p0, hi0 = grp_pad[lt0], task_pos_pad[lt0], high_pad[lt0]
+                high_head0 = high_head0.at[jnp.where(hi0, g0, NG)].min(
+                    p0, mode="drop"
+                )
+                low_head0 = low_head0.at[jnp.where(hi0, NG, g0)].min(
+                    p0, mode="drop"
+                )
 
         # -- 1. free capacity per group (the runtime's completion stage,
         #       gathered into the [NG, S] group grid; a crashed worker holds
         #       its recovery time, shrinking group capacity; pads read busy)
-        free = jnp.concatenate([free_w, jnp.zeros(1, jnp.bool_)])[wg]  # [NG,S]
-        free_u = free & ~reserved
-        free_r = free & reserved
-        nfu = jnp.sum(free_u, axis=1, dtype=jnp.int32)             # int32[NG]
-        nfr = jnp.sum(free_r, axis=1, dtype=jnp.int32)
+        with jax.named_scope("simx.pigeon.wfq"):
+            free = jnp.concatenate([free_w, jnp.zeros(1, jnp.bool_)])[wg]  # [NG,S]
+            free_u = free & ~reserved
+            free_r = free & reserved
+            nfu = jnp.sum(free_u, axis=1, dtype=jnp.int32)             # int32[NG]
+            nfr = jnp.sum(free_r, axis=1, dtype=jnp.int32)
 
-        # -- 2. queued counts + WFQ split of unreserved capacity ------------
-        if faults is None:
-            wh, qh = window(high_fifo, high_head0, t)
-            wl, ql = window(low_fifo, low_head0, t)
-        else:
-            wh, qh, fifo_h = window_fault(high_fifo, high_head0, t, task_finish0)
-            wl, ql, fifo_l = window_fault(low_fifo, low_head0, t, task_finish0)
-        total_u = jnp.minimum(nfu, qh + ql)
-        lead = jnp.maximum(0, weight - s.since_low)  # highs before first low
-        low_wfq = jnp.where(
-            total_u > lead, 1 + (total_u - lead - 1) // (weight + 1), 0
-        )
-        n_low = jnp.clip(low_wfq, jnp.maximum(total_u - qh, 0), jnp.minimum(ql, total_u))
-        n_high_u = total_u - n_low
-        n_high_r = jnp.minimum(qh - n_high_u, nfr)  # overflow onto reserved
-        since_low = jnp.maximum(0, s.since_low + n_high_u - weight * n_low)
+            # -- 2. queued counts + WFQ split of unreserved capacity --------
+            if faults is None:
+                wh, qh = window(high_fifo, high_head0, t)
+                wl, ql = window(low_fifo, low_head0, t)
+            else:
+                wh, qh, fifo_h = window_fault(high_fifo, high_head0, t, task_finish0)
+                wl, ql, fifo_l = window_fault(low_fifo, low_head0, t, task_finish0)
+            total_u = jnp.minimum(nfu, qh + ql)
+            lead = jnp.maximum(0, weight - s.since_low)  # highs before first low
+            low_wfq = jnp.where(
+                total_u > lead, 1 + (total_u - lead - 1) // (weight + 1), 0
+            )
+            n_low = jnp.clip(
+                low_wfq, jnp.maximum(total_u - qh, 0), jnp.minimum(ql, total_u)
+            )
+            n_high_u = total_u - n_low
+            n_high_r = jnp.minimum(qh - n_high_u, nfr)  # overflow onto reserved
+            since_low = jnp.maximum(0, s.since_low + n_high_u - weight * n_low)
 
         # -- 3. rank-and-select free workers, map ranks to FIFO positions ---
-        ranks_u = match_fn(free_u, n_high_u + n_low)               # int32[NG,S]
-        ranks_r = match_fn(free_r, n_high_r)
-        if faults is None:
-            # no holes: the r-th queued task sits at window position r
-            ru = jnp.clip(ranks_u, 0, C - 1)
-            task_u = jnp.where(
-                ranks_u < 0,
-                T,
-                jnp.where(
-                    ranks_u < n_high_u[:, None],
-                    jnp.take_along_axis(wh, ru, axis=1),
-                    jnp.take_along_axis(
-                        wl, jnp.clip(ranks_u - n_high_u[:, None], 0, C - 1), axis=1
+        with jax.named_scope("simx.pigeon.match"):
+            ranks_u = match_fn(free_u, n_high_u + n_low)               # int32[NG,S]
+            ranks_r = match_fn(free_r, n_high_r)
+            if faults is None:
+                # no holes: the r-th queued task sits at window position r
+                ru = jnp.clip(ranks_u, 0, C - 1)
+                task_u = jnp.where(
+                    ranks_u < 0,
+                    T,
+                    jnp.where(
+                        ranks_u < n_high_u[:, None],
+                        jnp.take_along_axis(wh, ru, axis=1),
+                        jnp.take_along_axis(
+                            wl, jnp.clip(ranks_u - n_high_u[:, None], 0, C - 1), axis=1
+                        ),
                     ),
-                ),
-            )
-            task_r = jnp.where(
-                ranks_r < 0,
-                T,
-                jnp.take_along_axis(
-                    wh, jnp.clip(n_high_u[:, None] + ranks_r, 0, C - 1), axis=1
-                ),
-            )
-        else:
-            # rank -> sorted queued position -> window task id
-            pos_uh = jnp.take_along_axis(
-                fifo_h, jnp.clip(ranks_u, 0, C - 1), axis=1
-            )
-            pos_ul = jnp.take_along_axis(
-                fifo_l, jnp.clip(ranks_u - n_high_u[:, None], 0, C - 1), axis=1
-            )
-            task_u = jnp.where(
-                ranks_u < 0,
-                T,
-                jnp.where(
-                    ranks_u < n_high_u[:, None],
-                    jnp.take_along_axis(wh, jnp.clip(pos_uh, 0, C - 1), axis=1),
-                    jnp.take_along_axis(wl, jnp.clip(pos_ul, 0, C - 1), axis=1),
-                ),
-            )
-            pos_r = jnp.take_along_axis(
-                fifo_h, jnp.clip(n_high_u[:, None] + ranks_r, 0, C - 1), axis=1
-            )
-            task_r = jnp.where(
-                ranks_r < 0,
-                T,
-                jnp.take_along_axis(wh, jnp.clip(pos_r, 0, C - 1), axis=1),
-            )
-        task_g = jnp.minimum(task_u, task_r)  # disjoint slots: one is T
-        launch = task_g < T                                         # [NG,S]
+                )
+                task_r = jnp.where(
+                    ranks_r < 0,
+                    T,
+                    jnp.take_along_axis(
+                        wh, jnp.clip(n_high_u[:, None] + ranks_r, 0, C - 1), axis=1
+                    ),
+                )
+            else:
+                # rank -> sorted queued position -> window task id
+                pos_uh = jnp.take_along_axis(
+                    fifo_h, jnp.clip(ranks_u, 0, C - 1), axis=1
+                )
+                pos_ul = jnp.take_along_axis(
+                    fifo_l, jnp.clip(ranks_u - n_high_u[:, None], 0, C - 1), axis=1
+                )
+                task_u = jnp.where(
+                    ranks_u < 0,
+                    T,
+                    jnp.where(
+                        ranks_u < n_high_u[:, None],
+                        jnp.take_along_axis(wh, jnp.clip(pos_uh, 0, C - 1), axis=1),
+                        jnp.take_along_axis(wl, jnp.clip(pos_ul, 0, C - 1), axis=1),
+                    ),
+                )
+                pos_r = jnp.take_along_axis(
+                    fifo_h, jnp.clip(n_high_u[:, None] + ranks_r, 0, C - 1), axis=1
+                )
+                task_r = jnp.where(
+                    ranks_r < 0,
+                    T,
+                    jnp.take_along_axis(wh, jnp.clip(pos_r, 0, C - 1), axis=1),
+                )
+            task_g = jnp.minimum(task_u, task_r)  # disjoint slots: one is T
+            launch = task_g < T                                         # [NG,S]
 
         # -- 4. launch: client->distributor->coordinator->worker = 3 hops ---
-        start = t + 3 * cfg.hop
-        fin = start + dur_pad[jnp.minimum(task_g, T)]
-        task_finish = task_finish0.at[jnp.where(launch, task_g, T)].set(
-            fin, mode="drop"
-        )
-        worker_finish = worker_finish0.at[jnp.where(launch, wg, W)].set(
-            fin, mode="drop"
-        )
-        worker_task = s.worker_task.at[jnp.where(launch, wg, W)].set(
-            task_g, mode="drop"
-        )
-        # messages: one distributor->coordinator per arriving task, one
-        # coordinator->worker per launch
-        arrived = jnp.sum(
-            (tasks.submit > t - cfg.dt) & (tasks.submit <= t), dtype=jnp.int32
-        )
-        messages = (
-            s.messages + arrived + jnp.sum(launch, dtype=jnp.int32)
-        )
-
-        # -- 5. head advance ------------------------------------------------
-        if faults is None:
-            # strict FIFO launches: advance by the launch counts
-            high_head = jnp.minimum(high_head0 + n_high_u + n_high_r, len_h)
-            low_head = jnp.minimum(low_head0 + n_low, len_l)
-        else:
-            # rolled-back windows have holes: advance past the launched
-            # prefix instead (equals the counts whenever there are none).
-            # Pads read NOT launched here (unlike ``rt.window_launched``):
-            # the head stops at the real tail instead of running through
-            # the pad slots.
-            fpad2 = rt.finish_pad(task_finish)
-            lead_h = rt.launched_lead(~jnp.isinf(fpad2[wh]))
-            lead_l = rt.launched_lead(~jnp.isinf(fpad2[wl]))
-            high_head = jnp.minimum(high_head0 + lead_h, len_h)
-            low_head = jnp.minimum(low_head0 + lead_l, len_l)
-
-        upd = dict(
-            task_finish=task_finish,
-            worker_finish=worker_finish,
-            worker_task=worker_task,
-            high_head=high_head,
-            low_head=low_head,
-            since_low=since_low,
-            messages=messages,
-        )
-        if telemetry:
-            upd["telemetry"] = dict(
-                launches=jnp.sum(launch, dtype=jnp.int32),
-                reserve_hits=jnp.sum(n_high_r, dtype=jnp.int32),
+        with jax.named_scope("simx.pigeon.launch"):
+            start = t + 3 * cfg.hop
+            fin = start + dur_pad[jnp.minimum(task_g, T)]
+            task_finish = task_finish0.at[jnp.where(launch, task_g, T)].set(
+                fin, mode="drop"
             )
-        if provenance:
-            # attempt = the task sat in its group coordinator's queued
-            # window this round (the submitted prefix — or the explicit
-            # queued mask under fault rollbacks).  authority = the group
-            # coordinator, which is static per worker.
-            col = jnp.arange(C, dtype=jnp.int32)[None, :]
+            worker_finish = worker_finish0.at[jnp.where(launch, wg, W)].set(
+                fin, mode="drop"
+            )
+            worker_task = s.worker_task.at[jnp.where(launch, wg, W)].set(
+                task_g, mode="drop"
+            )
+            # messages: one distributor->coordinator per arriving task, one
+            # coordinator->worker per launch
+            arrived = jnp.sum(
+                (tasks.submit > t - cfg.dt) & (tasks.submit <= t), dtype=jnp.int32
+            )
+            messages = (
+                s.messages + arrived + jnp.sum(launch, dtype=jnp.int32)
+            )
+
+            # -- 5. head advance --------------------------------------------
             if faults is None:
-                att_h = col < qh[:, None]
-                att_l = col < ql[:, None]
+                # strict FIFO launches: advance by the launch counts
+                high_head = jnp.minimum(high_head0 + n_high_u + n_high_r, len_h)
+                low_head = jnp.minimum(low_head0 + n_low, len_l)
             else:
-                fpad_a = rt.finish_pad(task_finish0)
-                att_h = jnp.isinf(fpad_a[wh]) & (
-                    jnp.where(wh >= T, jnp.inf, submit_pad[jnp.minimum(wh, T)])
-                    <= t
-                )
-                att_l = jnp.isinf(fpad_a[wl]) & (
-                    jnp.where(wl >= T, jnp.inf, submit_pad[jnp.minimum(wl, T)])
-                    <= t
-                )
-            attempt = (
-                jnp.zeros(T, jnp.bool_)
-                .at[jnp.where(att_h, wh, T)]
-                .set(True, mode="drop")
-                .at[jnp.where(att_l, wl, T)]
-                .set(True, mode="drop")
+                # rolled-back windows have holes: advance past the launched
+                # prefix instead (equals the counts whenever there are none).
+                # Pads read NOT launched here (unlike ``rt.window_launched``):
+                # the head stops at the real tail instead of running through
+                # the pad slots.
+                fpad2 = rt.finish_pad(task_finish)
+                lead_h = rt.launched_lead(~jnp.isinf(fpad2[wh]))
+                lead_l = rt.launched_lead(~jnp.isinf(fpad2[wl]))
+                high_head = jnp.minimum(high_head0 + lead_h, len_h)
+                low_head = jnp.minimum(low_head0 + lead_l, len_l)
+
+            upd = dict(
+                task_finish=task_finish,
+                worker_finish=worker_finish,
+                worker_task=worker_task,
+                high_head=high_head,
+                low_head=low_head,
+                since_low=since_low,
+                messages=messages,
             )
-            upd["provenance"] = dict(attempt=attempt, authority=worker_group)
+        if telemetry:
+            with jax.named_scope("simx.telemetry"):
+                upd["telemetry"] = dict(
+                    launches=jnp.sum(launch, dtype=jnp.int32),
+                    reserve_hits=jnp.sum(n_high_r, dtype=jnp.int32),
+                )
+        if provenance:
+            with jax.named_scope("simx.provenance"):
+                # attempt = the task sat in its group coordinator's queued
+                # window this round (the submitted prefix — or the explicit
+                # queued mask under fault rollbacks).  authority = the group
+                # coordinator, which is static per worker.
+                col = jnp.arange(C, dtype=jnp.int32)[None, :]
+                if faults is None:
+                    att_h = col < qh[:, None]
+                    att_l = col < ql[:, None]
+                else:
+                    fpad_a = rt.finish_pad(task_finish0)
+                    att_h = jnp.isinf(fpad_a[wh]) & (
+                        jnp.where(wh >= T, jnp.inf, submit_pad[jnp.minimum(wh, T)])
+                        <= t
+                    )
+                    att_l = jnp.isinf(fpad_a[wl]) & (
+                        jnp.where(wl >= T, jnp.inf, submit_pad[jnp.minimum(wl, T)])
+                        <= t
+                    )
+                attempt = (
+                    jnp.zeros(T, jnp.bool_)
+                    .at[jnp.where(att_h, wh, T)]
+                    .set(True, mode="drop")
+                    .at[jnp.where(att_l, wl, T)]
+                    .set(True, mode="drop")
+                )
+                upd["provenance"] = dict(attempt=attempt, authority=worker_group)
         return upd
 
     return rt.compose_step(

@@ -309,7 +309,13 @@ def compose_step(
     advances the per-task lifecycle arrays after folding the state
     updates (``repro.simx.provenance.advance_provenance``).  Disabled,
     nothing provenance-related is built — the same bitwise compile-out
-    guarantee as the telemetry flag."""
+    guarantee as the telemetry flag.
+
+    Each runtime stage runs under a ``jax.named_scope`` (``simx.faults``,
+    ``simx.complete``, ``simx.metrics``, ``simx.telemetry``,
+    ``simx.provenance``), and each rule names its dispatch sections
+    ``simx.<rule>.<section>``: profiler metadata only, so the optimized
+    program is the same with or without them."""
     from repro.simx.provenance import advance_provenance
 
     T = tasks.num_tasks
@@ -317,31 +323,39 @@ def compose_step(
     def step(carry):
         s = carry[0] if provenance else carry
         t = s.t
-        task_finish0, worker_finish0, lost_w, n_lost = fault_stage(
-            faults, t, cfg.dt, s.task_finish, s.worker_finish, s.worker_task, T
-        )
-        free, comp = completion_masks(worker_finish0, t, cfg.dt)
+        with jax.named_scope("simx.faults"):
+            task_finish0, worker_finish0, lost_w, n_lost = fault_stage(
+                faults, t, cfg.dt, s.task_finish, s.worker_finish,
+                s.worker_task, T,
+            )
+        with jax.named_scope("simx.complete"):
+            free, comp = completion_masks(worker_finish0, t, cfg.dt)
         updates = dispatch(s, t, task_finish0, worker_finish0, free, comp, lost_w)
         tel = updates.pop("telemetry", None)
         pv = updates.pop("provenance", None)
-        if n_lost is not None:
-            updates["lost"] = s.lost + n_lost
-        new = s.replace(t=t + cfg.dt, rnd=s.rnd + 1, **updates)
+        with jax.named_scope("simx.metrics"):
+            if n_lost is not None:
+                updates["lost"] = s.lost + n_lost
+            new = s.replace(t=t + cfg.dt, rnd=s.rnd + 1, **updates)
         if provenance:
-            out = (
-                new,
-                advance_provenance(carry[1], s, new, task_finish0, tasks, pv or {}),
-            )
+            with jax.named_scope("simx.provenance"):
+                out = (
+                    new,
+                    advance_provenance(
+                        carry[1], s, new, task_finish0, tasks, pv or {}
+                    ),
+                )
         else:
             out = new
         if not telemetry:
             return out
-        counters = dict(tel or {})
-        for f in TELEMETRY_CORE_COUNTERS:
-            counters[f] = getattr(new, f) - getattr(s, f)
-        if isinstance(new, QueueState):
-            for f in TELEMETRY_QUEUE_COUNTERS:
+        with jax.named_scope("simx.telemetry"):
+            counters = dict(tel or {})
+            for f in TELEMETRY_CORE_COUNTERS:
                 counters[f] = getattr(new, f) - getattr(s, f)
+            if isinstance(new, QueueState):
+                for f in TELEMETRY_QUEUE_COUNTERS:
+                    counters[f] = getattr(new, f) - getattr(s, f)
         return out, counters
 
     return step

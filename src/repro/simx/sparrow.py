@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.simx import runtime as rt
+from repro.simx import runtime as rt, spans
 from repro.simx.faults import (
     FaultSchedule,
     jobs_with_reservation,
@@ -335,6 +335,7 @@ class ProbeLayout:
     window: int = dataclasses.field(metadata=dict(static=True))
 
 
+@spans.span("simx.build")
 def make_sparrow_step(
     cfg: SimxConfig,
     tasks: TaskArrays,
@@ -393,84 +394,89 @@ def make_sparrow_step(
         del comp, lost_w
 
         # -- 0. recycle completed jobs' slots, compact the queues -----------
-        resq, fill = compact_queues(s.resq, task_finish0, tasks.job, t, J)
+        with jax.named_scope("simx.sparrow.compact"):
+            resq, fill = compact_queues(s.resq, task_finish0, tasks.job, t, J)
 
         # -- 1. windowed probe insertion (edge list is in arrival order) ----
-        win_j, win_w, lead, ins, lagged = probe_window_slice(
-            edge_job, edge_worker, s.probe_head, C, job_submit_pad, t
-        )
-        resq, n_over = insert_probes(resq, fill, win_w, win_j, ins)
-        head = s.probe_head + lead
-        # a ready edge left beyond the window means the burst outran it:
-        # count the round so the probe latency is observable (insert_window)
-        lag = s.probe_lag + lagged.astype(jnp.int32)
-        # every probe RPC counts (and costs a message), kept or dropped
-        probes_ctr = s.probes + lead
-        messages = s.messages + lead
+        with jax.named_scope("simx.sparrow.insert"):
+            win_j, win_w, lead, ins, lagged = probe_window_slice(
+                edge_job, edge_worker, s.probe_head, C, job_submit_pad, t
+            )
+            resq, n_over = insert_probes(resq, fill, win_w, win_j, ins)
+            head = s.probe_head + lead
+            # a ready edge left beyond the window means the burst outran it:
+            # count the round so the probe latency is observable (insert_window)
+            lag = s.probe_lag + lagged.astype(jnp.int32)
+            # every probe RPC counts (and costs a message), kept or dropped
+            probes_ctr = s.probes + lead
+            messages = s.messages + lead
 
         # -- 2. late binding: idle workers serve their queue heads ----------
-        pend_task = jnp.isinf(task_finish0) & (tasks.submit <= t)   # bool[T]
-        pending = (
-            jnp.zeros(J + 1, jnp.int32)
-            .at[tasks.job]
-            .add(pend_task.astype(jnp.int32))
-        )
-        active = (resq < J) & (pending[jnp.minimum(resq, J)] > 0)   # bool[W,R]
-        job_pick = queue_head_pick(resq, active, match_fn, J)       # int32[W]
-        # orphan rescue: an inserted pending job with no live reservation
-        # anywhere (all probes dropped on full queues, or — under faults —
-        # every probed worker currently dead) may be served by any idle
-        # worker (dead workers never serve: worker_finish holds recovery)
-        dead = worker_dead(faults, t) if faults is not None else None
-        orphan = (
-            (edge_end <= head)
-            & (pending[:-1] > 0)
-            & ~jobs_with_reservation(resq, J, dead=dead)
-        )
-        rescue = jnp.min(jnp.where(orphan, j_idx, J))
-        job_pick = jnp.minimum(job_pick, rescue)
-        launch, task_pick = late_bind(
-            jnp.where(idle, job_pick, J), pend_task, tasks.job, job_start
-        )
-        # client->scheduler hop + worker->scheduler get-task RPC round trip
-        task_finish, worker_finish, worker_task = rt.apply_launch(
-            launch, task_pick, t + 3 * cfg.hop, dur_pad,
-            task_finish0, worker_finish0, s.worker_task, T,
-        )
-        messages = messages + 2 * jnp.sum(launch, dtype=jnp.int32)  # RPC + reply
+        with jax.named_scope("simx.sparrow.bind"):
+            pend_task = jnp.isinf(task_finish0) & (tasks.submit <= t)   # bool[T]
+            pending = (
+                jnp.zeros(J + 1, jnp.int32)
+                .at[tasks.job]
+                .add(pend_task.astype(jnp.int32))
+            )
+            active = (resq < J) & (pending[jnp.minimum(resq, J)] > 0)   # bool[W,R]
+            job_pick = queue_head_pick(resq, active, match_fn, J)       # int32[W]
+            # orphan rescue: an inserted pending job with no live reservation
+            # anywhere (all probes dropped on full queues, or — under faults —
+            # every probed worker currently dead) may be served by any idle
+            # worker (dead workers never serve: worker_finish holds recovery)
+            dead = worker_dead(faults, t) if faults is not None else None
+            orphan = (
+                (edge_end <= head)
+                & (pending[:-1] > 0)
+                & ~jobs_with_reservation(resq, J, dead=dead)
+            )
+            rescue = jnp.min(jnp.where(orphan, j_idx, J))
+            job_pick = jnp.minimum(job_pick, rescue)
+            launch, task_pick = late_bind(
+                jnp.where(idle, job_pick, J), pend_task, tasks.job, job_start
+            )
+            # client->scheduler hop + worker->scheduler get-task RPC round trip
+            task_finish, worker_finish, worker_task = rt.apply_launch(
+                launch, task_pick, t + 3 * cfg.hop, dur_pad,
+                task_finish0, worker_finish0, s.worker_task, T,
+            )
+            messages = messages + 2 * jnp.sum(launch, dtype=jnp.int32)  # RPC + reply
 
-        upd = dict(
-            task_finish=task_finish,
-            worker_finish=worker_finish,
-            worker_task=worker_task,
-            resq=resq,
-            probe_head=head,
-            res_overflow=s.res_overflow + n_over,
-            probe_lag=lag,
-            probes=probes_ctr,
-            messages=messages,
-        )
+            upd = dict(
+                task_finish=task_finish,
+                worker_finish=worker_finish,
+                worker_task=worker_task,
+                resq=resq,
+                probe_head=head,
+                res_overflow=s.res_overflow + n_over,
+                probe_lag=lag,
+                probes=probes_ctr,
+                messages=messages,
+            )
         if telemetry:
-            upd["telemetry"] = dict(launches=jnp.sum(launch, dtype=jnp.int32))
+            with jax.named_scope("simx.telemetry"):
+                upd["telemetry"] = dict(launches=jnp.sum(launch, dtype=jnp.int32))
         if provenance:
-            # attempt = a scheduler acted on the job this round: its probes
-            # were inserted into reservation queues (``ins`` carries the
-            # newly-inserted window prefix) or it was orphan-rescued; the
-            # runtime latches the first such round, and or-s in launches.
-            # authority = the job's home scheduler (jobs hash round-robin
-            # onto the ``num_gms`` stateless Sparrow schedulers).
-            att_j = (
-                jnp.zeros(J + 1, jnp.bool_)
-                .at[jnp.where(ins, win_j, J)]
-                .set(True, mode="drop")
-            )
-            att_j = att_j.at[:-1].max(orphan)
-            authority = (
-                tasks.job[jnp.minimum(worker_task, T - 1)] % cfg.num_gms
-            ).astype(jnp.int32)
-            upd["provenance"] = dict(
-                attempt=att_j[:-1][tasks.job], authority=authority
-            )
+            with jax.named_scope("simx.provenance"):
+                # attempt = a scheduler acted on the job this round: its probes
+                # were inserted into reservation queues (``ins`` carries the
+                # newly-inserted window prefix) or it was orphan-rescued; the
+                # runtime latches the first such round, and or-s in launches.
+                # authority = the job's home scheduler (jobs hash round-robin
+                # onto the ``num_gms`` stateless Sparrow schedulers).
+                att_j = (
+                    jnp.zeros(J + 1, jnp.bool_)
+                    .at[jnp.where(ins, win_j, J)]
+                    .set(True, mode="drop")
+                )
+                att_j = att_j.at[:-1].max(orphan)
+                authority = (
+                    tasks.job[jnp.minimum(worker_task, T - 1)] % cfg.num_gms
+                ).astype(jnp.int32)
+                upd["provenance"] = dict(
+                    attempt=att_j[:-1][tasks.job], authority=authority
+                )
         return upd
 
     return rt.compose_step(
