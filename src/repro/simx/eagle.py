@@ -61,8 +61,11 @@ from repro.simx.runtime import MatchFn, default_match_fn
 from repro.simx.sparrow import (
     ProbeLayout,
     build_probe_edges,
+    check_contiguous,
     compact_queues,
     insert_probes,
+    job_bounds,
+    job_counts,
     late_bind,
     probe_mask,
     probe_window_slice,
@@ -154,6 +157,7 @@ def make_eagle_step(
     J = tasks.num_jobs
     R = cfg.short_reserved
     if layout is None:
+        check_contiguous(tasks)
         k1, k2, k3 = jax.random.split(key, 3)
         edge_job, edge_worker, edge_end, P, C = build_probe_edges(
             k1, cfg, tasks, short_only=True
@@ -182,9 +186,7 @@ def make_eagle_step(
     job_submit_pad = jnp.concatenate([tasks.job_submit, jnp.float32([jnp.inf])])
     w_row = jnp.arange(W, dtype=jnp.int32)
     j_idx = jnp.arange(J, dtype=jnp.int32)
-    job_start = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(tasks.job_ntasks, dtype=jnp.int32)[:-1]]
-    )
+    job_start, job_end = job_bounds(tasks)
     # central FIFO: long task ids in submit (== task id) order, + CL sentinels
     if layout is None:
         long_ids = np.nonzero(np.asarray(tasks.job_est)[np.asarray(tasks.job)] >= cfg.long_threshold)[0]
@@ -235,7 +237,7 @@ def make_eagle_step(
 
         # -- 0b. recycle completed jobs' slots, compact the queues ----------
         with jax.named_scope("simx.eagle.compact"):
-            resq, fill = compact_queues(s.resq, task_finish0, tasks.job, t, J)
+            resq, fill = compact_queues(s.resq, task_finish0, t, job_start, job_end)
 
         # -- 1. windowed probe insertion with per-edge SSS re-routing -------
         with jax.named_scope("simx.eagle.insert"):
@@ -268,13 +270,11 @@ def make_eagle_step(
         # -- 2. sticky batch draining: completed workers keep their job -----
         with jax.named_scope("simx.eagle.drain"):
             pend_task = jnp.isinf(task_finish0) & (tasks.submit <= t)
-            pending = (
-                jnp.zeros(J, jnp.int32).at[tasks.job].add(pend_task.astype(jnp.int32))
-            )
+            c, base, pending = job_counts(pend_task, job_start, job_end)
             prev_job = job_pad[s.worker_task]                       # int32[W], J=none
-            pend_prev = jnp.concatenate([pending, jnp.zeros(1, jnp.int32)])[prev_job]
+            pend_prev = jnp.append(pending, 0)[prev_job]
             sticky_pick = jnp.where(comp & (pend_prev > 0), prev_job, J)
-            launch1, task1 = late_bind(sticky_pick, pend_task, tasks.job, job_start)
+            launch1, task1 = late_bind(sticky_pick, c, base, pending)
             # the worker already holds the job's spec: no extra hops
             task_finish, worker_finish, worker_task = apply_launch(
                 launch1, task1, t, task_finish0, worker_finish0, s.worker_task
@@ -283,15 +283,10 @@ def make_eagle_step(
         # -- 3. late binding: idle workers serve their queue heads ----------
         with jax.named_scope("simx.eagle.bind"):
             pend_task = jnp.isinf(task_finish) & (tasks.submit <= t)
-            pending = (
-                jnp.zeros(J + 1, jnp.int32)
-                .at[tasks.job]
-                .add(pend_task.astype(jnp.int32))
-            )
+            c, base, pending = job_counts(pend_task, job_start, job_end)
             idle = worker_finish <= t
-            active = (
-                (resq < J) & (pending[jnp.minimum(resq, J)] > 0) & idle[:, None]
-            )
+            pend_q = jnp.append(pending, 0)[jnp.minimum(resq, J)]  # int32[W,R]
+            active = (resq < J) & (pend_q > 0) & idle[:, None]
             job_pick = queue_head_pick(resq, active, pick_fn, J)    # int32[W]
             # orphan rescue (see the sparrow rule): a pending short job with no
             # live reservation anywhere may be served by any idle worker
@@ -299,12 +294,12 @@ def make_eagle_step(
             orphan = (
                 short_job
                 & (edge_end <= head)
-                & (pending[:-1] > 0)
+                & (pending > 0)
                 & ~jobs_with_reservation(resq, J, dead=dead)
             )
             rescue = jnp.min(jnp.where(orphan, j_idx, J))
             job_pick = jnp.where(idle, jnp.minimum(job_pick, rescue), J)
-            launch2, task2 = late_bind(job_pick, pend_task, tasks.job, job_start)
+            launch2, task2 = late_bind(job_pick, c, base, pending)
             start = t + 3 * cfg.hop  # get-task RPC round trip + launch
             task_finish, worker_finish, worker_task = apply_launch(
                 launch2, task2, start, task_finish, worker_finish, worker_task

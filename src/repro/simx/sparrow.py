@@ -23,6 +23,13 @@ megha's GM match.  Carried probe state is O(W * R) — independent of the
 trace length — plus O(d * T) static edge constants (the same order as the
 task arrays themselves); nothing is ever materialized at [J, W].
 
+**Per-job counts** — tasks are laid out contiguously per job, so a job's
+pending or unfinished count is one prefix sum over the [T] task mask read
+at the job's two boundaries (``job_counts``), and late binding finds a
+job's r-th pending task by a W-wide binary search of that prefix sum.  A
+round runs two [T] prefix sums (unfinished, pending) and no [T]-wide
+scatter.
+
 Approximations vs. the event backend (beyond round quantization, see
 ``engine``): a worker whose chosen job runs out of pending tasks this
 round (more claimants than tasks) retries next round instead of popping
@@ -64,48 +71,76 @@ from repro.simx.state import (
 )
 
 
+def job_bounds(tasks: TaskArrays) -> tuple[jax.Array, jax.Array]:
+    """``(start, end) int32[J]``: job j owns task slots ``[start[j],
+    end[j])``.  Tasks are exported contiguously per job, in job order
+    (``export_workload``; the streaming window keeps the layout with a pad
+    job that owns the trailing slots), so the boundaries are the running
+    task counts."""
+    end = jnp.cumsum(tasks.job_ntasks, dtype=jnp.int32)
+    return end - tasks.job_ntasks, end
+
+
+def check_contiguous(tasks: TaskArrays) -> None:
+    """Host-side guard on a concrete trace: raise unless every job's tasks
+    form one slice, in job order — the layout ``job_counts`` reads."""
+    job = np.asarray(tasks.job)
+    ntasks = np.asarray(tasks.job_ntasks)
+    if not np.array_equal(job, np.repeat(np.arange(ntasks.size, dtype=job.dtype), ntasks)):
+        raise ValueError("tasks must be laid out contiguously per job, in job order")
+
+
+def job_counts(
+    mask: jax.Array, job_start: jax.Array, job_end: jax.Array
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Per-job counts of a per-task mask, without a [T]-wide scatter.
+
+    Requires the contiguous per-job layout (``job_bounds``): one prefix
+    sum ``c`` over the mask, read at each job's two boundaries.  Returns
+    ``(c int32[T], base int32[J], count int32[J])`` where ``base[j]`` is
+    the number of set entries before job j's slice and ``count[j]`` the
+    number inside it."""
+    c = jnp.cumsum(mask, dtype=jnp.int32)
+
+    def before(i):
+        return jnp.where(i > 0, c[jnp.maximum(i - 1, 0)], 0)
+
+    base = before(job_start)
+    return c, base, before(job_end) - base
+
+
 def late_bind(
-    job_pick: jax.Array, pend_task: jax.Array, job: jax.Array, job_start: jax.Array
+    job_pick: jax.Array, c: jax.Array, base: jax.Array, pending: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
     """Late-binding core shared by the sparrow and eagle rules: worker ``w``
     serves job ``job_pick[w]`` (``J`` = no claim); the k-th serving worker of
     job j (worker-index order, capped at j's pending count) gets j's k-th
-    pending task.  Tasks must be exported contiguously per job (the
-    ``export_workload`` layout): the cumulative task count before each job
-    (``job_start``) turns one global cumsum over ``pend_task`` into
-    within-job pending ranks.  Returns ``(launch bool[W], task int32[W])``
-    with ``T`` meaning none.
+    pending task.  ``(c, base, pending)`` is ``job_counts`` of the pending
+    mask, so tasks must be laid out contiguously per job.  Returns
+    ``(launch bool[W], task int32[W])`` with ``T`` meaning none.
 
-    O(T + W log W): serve ranks come from one stable sort of ``job_pick``
-    plus a first-occurrence ``searchsorted``, and the (job, rank) -> task
-    lookup is a single [T] scatter into the contiguous task layout (job
-    j's r-th pending task is written at ``job_start[j] + r``, which stays
-    inside j's slice).  Bitwise-equal to the retired dense [J, W]
+    Serve ranks come from one stable sort of ``job_pick`` and a running
+    max of the sorted runs' first positions.  Pending tasks keep their index
+    order, so j's r-th pending task is where ``c`` first exceeds
+    ``base[j] + r``: a W-wide binary search of ``c`` (log T steps),
+    which lands inside j's slice because ``r < pending[j]``.  No [T]-wide
+    array is built.  Bitwise-equal to the retired dense [J, W]
     formulation — ``tests/test_simx_queues.py`` pins this against an
-    in-test dense reference.
+    in-test dense reference, holes in the pending mask included.
     """
-    T = job.shape[0]
+    T = c.shape[0]
     W = job_pick.shape[0]
-    J = job_start.shape[0]
-    t_row = jnp.arange(T, dtype=jnp.int32)
+    J = base.shape[0]
     w_row = jnp.arange(W, dtype=jnp.int32)
-    pend_i = pend_task.astype(jnp.int32)
-    pending = jnp.zeros(J, jnp.int32).at[job].add(pend_i)
-    c = jnp.cumsum(pend_i, dtype=jnp.int32)
-    base = jnp.where(job_start > 0, c[jnp.maximum(job_start - 1, 0)], 0)
-    prank = c - 1 - base[job]                                   # int32[T]
-    slot = jnp.full(T, T, jnp.int32).at[
-        jnp.where(pend_task, job_start[job] + prank, T)
-    ].set(t_row, mode="drop")                                   # int32[T]
     order = jnp.argsort(job_pick, stable=True)
     sj = job_pick[order]
-    first = jnp.searchsorted(sj, sj, side="left").astype(jnp.int32)
+    run_head = jnp.concatenate([jnp.ones(1, jnp.bool_), sj[1:] != sj[:-1]])
+    first = jax.lax.cummax(jnp.where(run_head, w_row, 0))
     rank = jnp.zeros(W, jnp.int32).at[order].set(w_row - first)
     jp = jnp.clip(job_pick, 0, J - 1)
     serve = (job_pick < J) & (rank < pending[jp])
-    pos = job_start[jp] + rank
-    task_pick = jnp.where(serve, slot[jnp.clip(pos, 0, T - 1)], T)
-    return serve, task_pick
+    task = jnp.searchsorted(c, base[jp] + rank, side="right").astype(jnp.int32)
+    return serve, jnp.where(serve, task, T)
 
 
 def probe_targets(
@@ -265,21 +300,25 @@ def insert_probes(
 
 
 def compact_queues(
-    resq: jax.Array, task_finish: jax.Array, job: jax.Array, t: jax.Array, num_jobs: int
+    resq: jax.Array,
+    task_finish: jax.Array,
+    t: jax.Array,
+    job_start: jax.Array,
+    job_end: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
     """Recycle queue slots of completed jobs and re-compact each queue.
 
     An entry lives while its job still has an unfinished task (launched-
     but-running included, so a crash re-pending a task finds the job's
     reservations intact); live entries slide to the front preserving
-    order.  Returns ``(resq, fill int32[W])``.
+    order.  Tasks must be laid out contiguously per job: ``job_start`` /
+    ``job_end`` (``job_bounds``) turn one prefix sum into the per-job
+    unfinished counts.  Returns ``(resq, fill int32[W])``.
     """
     W, R = resq.shape
-    unfinished = (
-        jnp.zeros(num_jobs + 1, jnp.int32)
-        .at[job]
-        .add((task_finish > t).astype(jnp.int32))
-    )
+    num_jobs = job_start.shape[0]
+    unfinished = job_counts(task_finish > t, job_start, job_end)[2]
+    unfinished = jnp.append(unfinished, 0)                      # J = empty
     live = (resq < num_jobs) & (unfinished[jnp.minimum(resq, num_jobs)] > 0)
     pos = jnp.cumsum(live, axis=1) - 1
     w_rows = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[:, None], (W, R))
@@ -367,6 +406,7 @@ def make_sparrow_step(
     T = tasks.num_tasks
     J = tasks.num_jobs
     if layout is None:
+        check_contiguous(tasks)
         edge_job, edge_worker, edge_end, P, C = build_probe_edges(key, cfg, tasks)
     else:
         if faults is not None:
@@ -380,11 +420,7 @@ def make_sparrow_step(
     job_submit_pad = jnp.concatenate([tasks.job_submit, jnp.float32([jnp.inf])])
     j_idx = jnp.arange(J, dtype=jnp.int32)
     dur_pad = jnp.concatenate([tasks.duration, jnp.float32([0.0])])
-    # tasks are exported contiguously per job: cumulative task count before
-    # each job gives the within-job pending rank via one global cumsum
-    job_start = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(tasks.job_ntasks, dtype=jnp.int32)[:-1]]
-    )
+    job_start, job_end = job_bounds(tasks)
 
     def dispatch(s, t, task_finish0, worker_finish0, idle, comp, lost_w):
         # completions are implicit: a worker is idle iff worker_finish <= t
@@ -395,7 +431,7 @@ def make_sparrow_step(
 
         # -- 0. recycle completed jobs' slots, compact the queues -----------
         with jax.named_scope("simx.sparrow.compact"):
-            resq, fill = compact_queues(s.resq, task_finish0, tasks.job, t, J)
+            resq, fill = compact_queues(s.resq, task_finish0, t, job_start, job_end)
 
         # -- 1. windowed probe insertion (edge list is in arrival order) ----
         with jax.named_scope("simx.sparrow.insert"):
@@ -414,12 +450,9 @@ def make_sparrow_step(
         # -- 2. late binding: idle workers serve their queue heads ----------
         with jax.named_scope("simx.sparrow.bind"):
             pend_task = jnp.isinf(task_finish0) & (tasks.submit <= t)   # bool[T]
-            pending = (
-                jnp.zeros(J + 1, jnp.int32)
-                .at[tasks.job]
-                .add(pend_task.astype(jnp.int32))
-            )
-            active = (resq < J) & (pending[jnp.minimum(resq, J)] > 0)   # bool[W,R]
+            c, base, pending = job_counts(pend_task, job_start, job_end)
+            pend_q = jnp.append(pending, 0)[jnp.minimum(resq, J)]      # int32[W,R]
+            active = (resq < J) & (pend_q > 0)                          # bool[W,R]
             job_pick = queue_head_pick(resq, active, match_fn, J)       # int32[W]
             # orphan rescue: an inserted pending job with no live reservation
             # anywhere (all probes dropped on full queues, or — under faults —
@@ -428,13 +461,13 @@ def make_sparrow_step(
             dead = worker_dead(faults, t) if faults is not None else None
             orphan = (
                 (edge_end <= head)
-                & (pending[:-1] > 0)
+                & (pending > 0)
                 & ~jobs_with_reservation(resq, J, dead=dead)
             )
             rescue = jnp.min(jnp.where(orphan, j_idx, J))
             job_pick = jnp.minimum(job_pick, rescue)
             launch, task_pick = late_bind(
-                jnp.where(idle, job_pick, J), pend_task, tasks.job, job_start
+                jnp.where(idle, job_pick, J), c, base, pending
             )
             # client->scheduler hop + worker->scheduler get-task RPC round trip
             task_finish, worker_finish, worker_task = rt.apply_launch(

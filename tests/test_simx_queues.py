@@ -3,8 +3,12 @@
 * the retired dense [J, W] sparrow path, kept here as a reference
   implementation, is reproduced BITWISE by the queue path when the cap
   and insertion window are ample;
-* ``late_bind``'s O(T + W log W) rewrite equals the dense [J, W]
-  formulation on random inputs;
+* ``late_bind``'s prefix-sum search equals the dense [J, W]
+  formulation on random inputs, holes in the pending mask included;
+* the per-job prefix-sum counts (``job_counts``, ``compact_queues``)
+  equal the retired [T]-wide scatters on random contiguous layouts, and
+  sparrow and eagle built on the retired scatters reach the same final
+  state bit for bit, with and without faults;
 * eagle's per-edge SSS re-routing lands probes on exactly the dense
   rejection/re-route formula's cells;
 * probe sampling is rank-based: every job probes exactly
@@ -22,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.simx import SimxConfig, engine, export_workload
+from repro.simx import SimxConfig, empty_schedule, engine, export_workload
 from repro.simx import eagle as simx_eagle
 from repro.simx import sparrow as simx_sparrow
 from repro.simx import sweep as simx_sweep
@@ -67,6 +71,117 @@ def dense_late_bind(job_pick, pend_task, job, job_start):
         axis=0,
     )                                                           # int32[W]
     return jnp.any(serve, axis=0), task_pick
+
+
+def scatter_late_bind(job_pick, pend_task, job, job_start):
+    """The retired O(T + W log W) late binding: per-job pending counts and
+    a [T] slot table, both scattered over every task."""
+    T = job.shape[0]
+    W = job_pick.shape[0]
+    J = job_start.shape[0]
+    t_row = jnp.arange(T, dtype=jnp.int32)
+    w_row = jnp.arange(W, dtype=jnp.int32)
+    pend_i = pend_task.astype(jnp.int32)
+    pending = jnp.zeros(J, jnp.int32).at[job].add(pend_i)
+    c = jnp.cumsum(pend_i, dtype=jnp.int32)
+    base = jnp.where(job_start > 0, c[jnp.maximum(job_start - 1, 0)], 0)
+    prank = c - 1 - base[job]
+    slot = jnp.full(T, T, jnp.int32).at[
+        jnp.where(pend_task, job_start[job] + prank, T)
+    ].set(t_row, mode="drop")
+    order = jnp.argsort(job_pick, stable=True)
+    sj = job_pick[order]
+    first = jnp.searchsorted(sj, sj, side="left").astype(jnp.int32)
+    rank = jnp.zeros(W, jnp.int32).at[order].set(w_row - first)
+    jp = jnp.clip(job_pick, 0, J - 1)
+    serve = (job_pick < J) & (rank < pending[jp])
+    pos = job_start[jp] + rank
+    return serve, jnp.where(serve, slot[jnp.clip(pos, 0, T - 1)], T)
+
+
+def scatter_compact_queues(resq, task_finish, job, t, num_jobs):
+    """The retired queue compaction: unfinished counts by a [T] scatter."""
+    W, R = resq.shape
+    unfinished = (
+        jnp.zeros(num_jobs + 1, jnp.int32)
+        .at[job]
+        .add((task_finish > t).astype(jnp.int32))
+    )
+    live = (resq < num_jobs) & (unfinished[jnp.minimum(resq, num_jobs)] > 0)
+    pos = jnp.cumsum(live, axis=1) - 1
+    w_rows = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[:, None], (W, R))
+    out = (
+        jnp.full((W, R), num_jobs, jnp.int32)
+        .at[w_rows, jnp.where(live, pos, R)]
+        .set(resq, mode="drop")
+    )
+    return out, jnp.sum(live, axis=1, dtype=jnp.int32)
+
+
+def random_layout(rng, mode):
+    """A random contiguous per-job layout and a per-task mask over it:
+    ``(ntasks, job, job_start, job_end, mask)``.
+
+    ``random``: iid mask.  ``repend``: each job launched a prefix of its
+    tasks, then some launched tasks re-pended (fault holes: pending is
+    not a suffix).  ``pad``: a streaming window — empty job slots and a
+    pad job owning the trailing task slots, which never pend.  ``edge``:
+    the first and last jobs fully pending, every other job's mask empty
+    or full.  Every mode has jobs with zero set entries."""
+    J = int(rng.integers(4, 10))
+    ntasks = rng.integers(1, 12, J)
+    if mode == "pad":
+        ntasks[rng.random(J) < 0.3] = 0                     # empty slots
+        ntasks[-1] = rng.integers(5, 20)                    # the pad job
+    T = int(ntasks.sum())
+    job = np.repeat(np.arange(J), ntasks).astype(np.int32)
+    end = np.cumsum(ntasks).astype(np.int32)
+    start = (end - ntasks).astype(np.int32)
+    if mode == "random":
+        mask = rng.random(T) < 0.5
+    elif mode == "repend":
+        launched = np.arange(T) - start[job] < rng.integers(0, ntasks + 1)[job]
+        mask = ~launched | (launched & (rng.random(T) < 0.3))
+    elif mode == "pad":
+        mask = (rng.random(T) < 0.6) & (job < J - 1)
+    else:
+        mask = np.repeat(rng.random(J) < 0.5, ntasks)
+        mask[job == 0] = mask[job == J - 1] = True
+    mask[job == rng.integers(0, J)] = False                 # a job with none
+    return ntasks, job, start, end, mask
+
+
+LAYOUT_MODES = ("random", "repend", "pad", "edge")
+
+
+@pytest.mark.parametrize("mode", LAYOUT_MODES)
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_job_counts_and_compaction_match_scatter(mode, seed):
+    """Property: the per-job prefix-sum counts equal the [T]-wide scatter,
+    and ``compact_queues`` equals its scatter formulation bit for bit."""
+    rng = np.random.default_rng([seed, LAYOUT_MODES.index(mode)])
+    ntasks, job, start, end, mask = random_layout(rng, mode)
+    J = ntasks.size
+    c, base, count = simx_sparrow.job_counts(
+        jnp.asarray(mask), jnp.asarray(start), jnp.asarray(end)
+    )
+    np.testing.assert_array_equal(np.asarray(count), np.bincount(job, mask, J))
+    np.testing.assert_array_equal(np.asarray(c), np.cumsum(mask))
+    np.testing.assert_array_equal(
+        np.asarray(base), [mask[:s].sum() for s in start]
+    )
+    W, R = 11, 5
+    t = jnp.float32(1.0)
+    task_finish = jnp.asarray(
+        np.where(mask, np.inf, rng.choice([0.5, 1.0, 2.0], job.size)), jnp.float32
+    )
+    resq = jnp.asarray(rng.integers(0, J + 1, (W, R)), jnp.int32)
+    got = simx_sparrow.compact_queues(
+        resq, task_finish, t, jnp.asarray(start), jnp.asarray(end)
+    )
+    want = scatter_compact_queues(resq, task_finish, jnp.asarray(job), t, J)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def run_dense_sparrow(cfg, tasks, seed, num_rounds):
@@ -170,25 +285,134 @@ def test_queue_path_matches_dense_with_auto_knobs(small):
     assert jnp.array_equal(q.task_finish, fin)
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+@pytest.mark.parametrize("mod", [simx_sparrow, simx_eagle])
+def test_non_contiguous_layout_is_refused(small, mod):
+    """The per-job prefix sums read each job's tasks as one slice: a fixed
+    trace whose tasks interleave jobs is refused when the step is built."""
+    tasks = small
+    job = np.asarray(tasks.job).copy()
+    job[[0, -1]] = job[[-1, 0]]
+    mixed = dataclasses.replace(tasks, job=jnp.asarray(job))
+    cfg = SimxConfig(num_workers=48, dt=0.02)
+    with pytest.raises(ValueError, match="contiguously"):
+        mod.simulate_fixed(cfg, mixed, 0, 1)
+
+
+@pytest.mark.parametrize(
+    "seed",
+    [0, 1, 2, 3]
+    + [
+        pytest.param((mode, s), id=f"{mode}-{s}")
+        for mode in ("repend", "pad", "edge")
+        for s in (0, 1, 2)
+    ],
+)
 def test_late_bind_matches_dense_reference(seed):
-    """Property: the O(T + W log W) late_bind equals the dense [J, W]
-    formulation on random claim patterns (incl. over-claimed jobs, idle
-    workers, and jobs with zero pending tasks)."""
-    rng = np.random.default_rng(seed)
-    J, W = 7, 33
-    ntasks = rng.integers(1, 9, J)
-    T = int(ntasks.sum())
-    job = jnp.asarray(np.repeat(np.arange(J), ntasks), jnp.int32)
-    job_start = jnp.asarray(
-        np.concatenate([[0], np.cumsum(ntasks)[:-1]]), jnp.int32
-    )
-    pend = jnp.asarray(rng.random(T) < 0.5)
+    """Property: the prefix-sum late_bind equals the dense [J, W]
+    formulation (and the retired [T] slot scatter) on random claim
+    patterns (incl. over-claimed jobs, idle workers, and jobs with zero
+    pending tasks); the layout cases add re-pended holes, a streaming pad
+    job and fully pending first and last jobs."""
+    if isinstance(seed, tuple):
+        mode, s = seed
+        rng = np.random.default_rng([s, LAYOUT_MODES.index(mode)])
+        ntasks, job_np, start, end, pend_np = random_layout(rng, mode)
+        J, W = ntasks.size, 33
+    else:
+        rng = np.random.default_rng(seed)
+        J, W = 7, 33
+        ntasks = rng.integers(1, 9, J)
+        T = int(ntasks.sum())
+        job_np = np.repeat(np.arange(J), ntasks)
+        end = np.cumsum(ntasks)
+        start = end - ntasks
+        pend_np = rng.random(T) < 0.5
+    job = jnp.asarray(job_np, jnp.int32)
+    job_start, job_end = jnp.asarray(start, jnp.int32), jnp.asarray(end, jnp.int32)
+    pend = jnp.asarray(pend_np)
     pick = jnp.asarray(rng.integers(0, J + 1, W), jnp.int32)  # J = no claim
-    l_new, t_new = simx_sparrow.late_bind(pick, pend, job, job_start)
+    counts = simx_sparrow.job_counts(pend, job_start, job_end)
+    l_new, t_new = simx_sparrow.late_bind(pick, *counts)
     l_old, t_old = dense_late_bind(pick, pend, job, job_start)
     np.testing.assert_array_equal(np.asarray(l_new), np.asarray(l_old))
     np.testing.assert_array_equal(np.asarray(t_new), np.asarray(t_old))
+    l_sc, t_sc = scatter_late_bind(pick, pend, job, job_start)
+    np.testing.assert_array_equal(np.asarray(l_new), np.asarray(l_sc))
+    np.testing.assert_array_equal(np.asarray(t_new), np.asarray(t_sc))
+
+
+@pytest.fixture
+def scatter_rules(monkeypatch):
+    """Rebuild sparrow and eagle on the retired [T]-wide scatters: per-job
+    counts scattered over ``tasks.job``, the [T] slot-table late binding
+    and the scatter compaction.  The patched ``job_counts`` hands the mask
+    itself through to ``late_bind`` in place of its prefix sum.  Returns
+    ``install(tasks)`` and the number of patched calls."""
+    calls = []
+
+    def install(tasks):
+        J = tasks.num_jobs
+        end = jnp.cumsum(tasks.job_ntasks, dtype=jnp.int32)
+        start = end - tasks.job_ntasks
+
+        def job_counts(mask, job_start, job_end):
+            calls.append("counts")
+            count = jnp.zeros(J, jnp.int32).at[tasks.job].add(mask.astype(jnp.int32))
+            return mask, None, count
+
+        def late_bind(job_pick, mask, base, pending):
+            calls.append("bind")
+            return scatter_late_bind(job_pick, mask, tasks.job, start)
+
+        def compact_queues(resq, task_finish, t, job_start, job_end):
+            calls.append("compact")
+            return scatter_compact_queues(resq, task_finish, tasks.job, t, J)
+
+        for mod in (simx_sparrow, simx_eagle):
+            monkeypatch.setattr(mod, "job_counts", job_counts)
+            monkeypatch.setattr(mod, "late_bind", late_bind)
+            monkeypatch.setattr(mod, "compact_queues", compact_queues)
+
+    return install, calls
+
+
+@pytest.mark.parametrize("faulty", [False, True], ids=["clean", "crashes"])
+@pytest.mark.parametrize("mod", [simx_sparrow, simx_eagle])
+def test_rule_matches_scatter_formulation_bitwise(mod, faulty, scatter_rules):
+    """Rule-level pin: sparrow and eagle reach the same final state, every
+    field and counter, as the same rules built on the retired [T]-wide
+    scatters — with and without repeated worker crashes (re-pended holes)
+    and an undersized queue cap (overflow, orphan rescue)."""
+    rng = np.random.default_rng(5)
+    jobs_wl = synthetic_trace(num_jobs=14, tasks_per_job=12, load=0.9, num_workers=40, seed=8)
+    tasks = export_workload(jobs_wl)
+    est = np.asarray(tasks.job_est).copy()
+    est[::4] = 99.0                                   # eagle's long jobs
+    tasks = dataclasses.replace(tasks, job_est=jnp.asarray(est))
+    cfg = SimxConfig(num_workers=40, dt=0.02, reserve_cap=3, long_threshold=10.0)
+    rounds = engine.estimate_rounds(cfg, tasks, slack=4.0)
+    faults = None
+    if faulty:
+        down = np.full(40, np.inf, np.float32)
+        hit = rng.permutation(40)[:20]
+        down[hit] = np.linspace(0.2, 3.0, 20).astype(np.float32)
+        faults = empty_schedule(40, cfg.num_gms).replace(
+            worker_down=jnp.asarray(down), worker_up=jnp.asarray(down + 0.1)
+        )
+    new = mod.simulate_fixed(cfg, tasks, 3, rounds, faults=faults)
+    install, calls = scatter_rules
+    install(tasks)
+    old = mod.simulate_fixed(cfg, tasks, 3, rounds, faults=faults)
+    assert {"counts", "bind", "compact"} <= set(calls)
+    if faulty:
+        assert int(new.lost) > 0
+    assert int(new.res_overflow) > 0
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(new), jax.tree_util.tree_leaves(old)
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path)
+        )
 
 
 @pytest.mark.parametrize("seed", [0, 5])
