@@ -426,6 +426,42 @@ def get_rule(name: str) -> Rule:
         ) from None
 
 
+def point_step(
+    name: str,
+    cfg: SimxConfig,
+    tasks: TaskArrays,
+    seed: jax.Array | int,
+    match_fn: MatchFn | None = None,
+    pick_fn: MatchFn | None = None,
+    faults: FaultSchedule | None = None,
+    telemetry: bool = False,
+    provenance: bool = False,
+) -> Callable:
+    """The round step of one simulated datacenter, seeded by ``seed`` (an
+    int, or an already-made PRNG key) — what ``simulate_fixed`` scans, and
+    what the sharded grid's chunk runner rebuilds inside every chunk from
+    each point's own inputs."""
+    key = jax.random.PRNGKey(seed) if jnp.ndim(seed) == 0 else seed
+    return get_rule(name).build_step(
+        cfg, tasks, key, match_fn=match_fn, pick_fn=pick_fn, faults=faults,
+        telemetry=telemetry, provenance=provenance,
+    )
+
+
+def init_carry(
+    name: str, cfg: SimxConfig, tasks: TaskArrays, provenance: bool = False
+):
+    """The fresh scan carry ``point_step``'s step advances: the rule's
+    idle datacenter, paired with fresh lifecycle arrays under
+    ``provenance``."""
+    state = get_rule(name).init(cfg, tasks)
+    if provenance:
+        from repro.simx.provenance import init_provenance
+
+        state = (state, init_provenance(tasks.num_tasks))
+    return state
+
+
 def simulate_fixed(
     name: str,
     cfg: SimxConfig,
@@ -454,17 +490,11 @@ def simulate_fixed(
     ``provenance=True`` switches on the lifecycle stage: the returned
     state becomes the ``(state, Provenance)`` carry (the Timeline, when
     also enabled, stays the second element of the outer tuple)."""
-    rule = get_rule(name)
-    key = jax.random.PRNGKey(seed) if jnp.ndim(seed) == 0 else seed
-    step = rule.build_step(
-        cfg, tasks, key, match_fn=match_fn, pick_fn=pick_fn, faults=faults,
-        telemetry=telemetry is not None, provenance=provenance,
+    step = point_step(
+        name, cfg, tasks, seed, match_fn=match_fn, pick_fn=pick_fn,
+        faults=faults, telemetry=telemetry is not None, provenance=provenance,
     )
-    state = rule.init(cfg, tasks)
-    if provenance:
-        from repro.simx.provenance import init_provenance
-
-        state = (state, init_provenance(tasks.num_tasks))
+    state = init_carry(name, cfg, tasks, provenance=provenance)
     if telemetry is None:
         return scan_rounds(step, state, num_rounds)
     from repro.simx import telemetry as tlm  # runtime <- telemetry cycle guard

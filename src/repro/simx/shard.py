@@ -11,12 +11,17 @@ across a 1-D ``"grid"`` mesh axis instead:
   * ``sweep_mesh(n_devices)`` builds the mesh (a function, never a
     module-level constant — the ``launch/mesh.py`` idiom — so importing
     this module never touches jax device state).
-  * ``sharded_sweep_grid`` / ``sharded_fig2_sweep`` flatten the
-    (load x seed) axes to one batch axis, pad it to a device multiple,
-    and run the existing vmapped point function under ``jax.pmap`` over
-    the mesh's devices: each device runs the plain vmapped program over
-    its local batch slice, closed-over structural arrays are replicated,
-    and no collective appears in the compiled program.
+  * ``ShardedGrid`` is the one grid program: it flattens the grid's
+    axes to one batch axis, pads it to a device multiple, and runs the
+    vmapped per-point round scan under ``jax.pmap`` over the mesh's
+    devices, ``chunk`` rounds a call (the ``simx_chunk`` runner, with a
+    done flag per point), so a grid can be timed, checked and stopped
+    between chunks.  Each device runs the plain vmapped program over its
+    local batch slice, closed-over structural arrays are replicated, and
+    no collective appears in the compiled program.
+  * ``fig2_grid`` builds it for the (load x seed) grid;
+    ``sharded_sweep_grid`` / ``sharded_fig2_sweep`` run that to the round
+    budget in chunks and reduce every point in-program;
     ``sharded_fig4_sweep`` gives the (severity x seed) fault grids the
     same treatment over the ``FaultSchedule`` leaves.
   * ``sharded_steady_state`` batches ``stream.run_steady_state``'s load
@@ -68,7 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.simx import runtime
+from repro.simx import runtime, spans
 from repro.simx import stream as _stream
 from repro.simx import sweep as _sweep
 from repro.simx import telemetry as tlm
@@ -80,6 +85,9 @@ from repro.workload.synth import ArrivalProcess
 #: The one mesh axis every sharded driver uses: the flattened batch of
 #: grid points (or steady-state lanes).
 GRID_AXIS = "grid"
+#: Rounds per call of the chunk runner when a grid runs to its whole
+#: round budget (``engine.run_to_completion``'s default chunk).
+DEFAULT_CHUNK = 256
 
 
 def sweep_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -164,39 +172,163 @@ def make_grid_shard(
     )
 
 
-def _batched_runner(
-    point: Callable, batch, n_real: int, rows: int, cols: int, mesh: Mesh
-) -> Callable[[], dict]:
-    """Wrap a per-point function into a zero-arg runner: pad the batch to
-    a device multiple, reshape it to ``[n_dev, per_dev, ...]``, run the
-    vmapped point under ``jax.pmap`` over the mesh's devices (each device
-    sweeps its local batch slice — no collective in the program; the
-    module docstring describes the executor), and slice/reshape the
-    outputs back to ``[rows, cols]`` on the host.  The runner can be
-    called repeatedly — the compiled program is reused, which is how the
-    bench separates compile wall from steady-state wall."""
-    n_dev = int(mesh.devices.size)
-    batch, n_padded = pad_batch(batch, n_real, n_dev)
-    per_dev = n_padded // n_dev
-    batch = jax.tree.map(
-        lambda x: jnp.reshape(x, (n_dev, per_dev) + x.shape[1:]), batch
-    )
-    prog = jax.pmap(
-        jax.vmap(point), axis_name=GRID_AXIS,
-        devices=list(mesh.devices.reshape(-1)),
-    )
+class ShardedGrid:
+    """A (row x col) grid of simulated datacenters laid over ``mesh`` as
+    one chunked program.
 
-    def run() -> dict[str, jax.Array]:
-        out = prog(batch)
-        return {
-            k: jnp.reshape(
-                jnp.reshape(v, (n_dev * per_dev,) + v.shape[2:])[:n_real],
-                (rows, cols) + v.shape[2:],
+    ``point(p)`` turns one entry of ``batch`` (a pytree with a leading
+    ``rows * cols`` axis, row-major) into ``(tasks, seed, faults)``.  The
+    batch is padded to a device multiple, laid out ``[n_dev, per_dev,
+    ...]`` and placed on the mesh once; every program below is the
+    per-point function vmapped over a device's slice and run under
+    ``jax.pmap`` (the module docstring describes the executor):
+
+      * ``init()`` builds every point's fresh carry (``simx.build`` span);
+      * ``runner(carry, batch)`` advances every point by ``chunk`` rounds
+        and returns ``(carry, done)``, ``done`` one flag a point, reduced
+        inside the chunk (``simx.done``) as ``engine.make_chunk_runner``
+        does.  It is the ``spans.Program`` named ``simx_chunk``; each
+        call rebuilds the points' steps from their own inputs, so every
+        point keeps its own PRNG key;
+      * ``run(carry, n)`` advances exactly ``n`` rounds: whole chunks,
+        then one remainder program;
+      * ``summary(carry)`` reduces each point to ``sweep.point_summary``.
+
+    ``gather`` brings a ``[n_dev, per_dev, ...]`` result back as ``[rows,
+    cols, ...]`` with the pad points sliced off.  Chunks compose: ``k``
+    chunks give the state ``k * chunk`` rounds of one scan give."""
+
+    def __init__(
+        self,
+        scheduler: str,
+        cfg: SimxConfig,
+        point: Callable,
+        batch,
+        rows: int,
+        cols: int,
+        mesh: Mesh,
+        *,
+        chunk: int,
+        match_fn: MatchFn | None = None,
+        pick_fn: MatchFn | None = None,
+        provenance: bool = False,
+    ):
+        name = scheduler.lower()
+        rule = runtime.get_rule(name)  # fail fast on unknown schedulers
+        if chunk < 1:
+            raise ValueError(f"ShardedGrid needs chunk >= 1, not {chunk}")
+        self.rows, self.cols, self.n_real = rows, cols, rows * cols
+        self.n_dev = int(mesh.devices.size)
+        batch, n_padded = pad_batch(batch, self.n_real, self.n_dev)
+        self.per_dev = n_padded // self.n_dev
+        self.batch = jax.device_put(
+            jax.tree.map(
+                lambda x: jnp.reshape(
+                    x, (self.n_dev, self.per_dev) + x.shape[1:]
+                ),
+                batch,
+            ),
+            grid_sharding(mesh),
+        )
+        self.chunk = int(chunk)
+        devices = list(mesh.devices.reshape(-1))
+
+        def sharded(fn):
+            return jax.pmap(jax.vmap(fn), axis_name=GRID_AXIS, devices=devices)
+
+        def fresh(p):
+            tasks, _, _ = point(p)
+            return runtime.init_carry(name, cfg, tasks, provenance=provenance)
+
+        def advance(n):
+            def simx_chunk(carry, p):
+                tasks, seed, faults = point(p)
+                step = runtime.point_step(
+                    name, cfg, tasks, seed, match_fn=match_fn,
+                    pick_fn=pick_fn, faults=faults, provenance=provenance,
+                )
+                carry = runtime.scan_rounds(step, carry, n)
+                s = runtime.carry_state(carry)
+                with jax.named_scope("simx.done"):
+                    done = jnp.all(s.task_finish <= s.t)
+                return carry, done
+
+            return sharded(simx_chunk)
+
+        def summarize(carry, p):
+            tasks, _, _ = point(p)
+            state, prov = carry if provenance else (carry, None)
+            return _sweep.point_summary(
+                state, tasks, has_queues=rule.has_queues, provenance=prov,
+                dt=cfg.dt,
             )
-            for k, v in out.items()
-        }
 
-    return run
+        self._init = sharded(fresh)
+        self._advance = functools.lru_cache(maxsize=None)(advance)
+        self.runner = spans.Program("simx_chunk", self._advance(self.chunk))
+        self._summary = sharded(summarize)
+
+    def init(self):
+        """Every point's fresh carry, ``[n_dev, per_dev, ...]`` on the
+        mesh."""
+        with spans.span("simx.build"):
+            return self._init(self.batch)
+
+    def run(self, carry, num_rounds: int):
+        """Advance every point by exactly ``num_rounds`` rounds."""
+        runtime.check_round_budget(num_rounds, "ShardedGrid.run(num_rounds=...)")
+        whole, rest = divmod(int(num_rounds), self.chunk)
+        for _ in range(whole):
+            carry, _ = self.runner(carry, self.batch)
+        if rest:
+            carry, _ = self._advance(rest)(carry, self.batch)
+        return carry
+
+    def summary(self, carry) -> dict[str, jax.Array]:
+        """``sweep.point_summary`` of every point, ``[rows, cols]``."""
+        return self.gather(self._summary(carry, self.batch))
+
+    def gather(self, tree):
+        """A ``[n_dev, per_dev, ...]`` result as ``[rows, cols, ...]``,
+        the pad points sliced off."""
+        n = self.n_dev * self.per_dev
+        return jax.tree.map(
+            lambda v: jnp.reshape(
+                jnp.reshape(v, (n,) + v.shape[2:])[: self.n_real],
+                (self.rows, self.cols) + v.shape[2:],
+            ),
+            tree,
+        )
+
+
+def fig2_grid(
+    scheduler: str,
+    cfg: SimxConfig,
+    tasks: TaskArrays,
+    submit_grid: jax.Array,      # float32[L, T]
+    job_submit_grid: jax.Array,  # float32[L, J]
+    seeds: jax.Array,            # int[S]
+    *,
+    chunk: int,
+    mesh: Optional[Mesh] = None,
+    match_fn: MatchFn | None = None,
+    pick_fn: MatchFn | None = None,
+    provenance: bool = False,
+) -> ShardedGrid:
+    """The chunked mesh-sharded (load x seed) grid: point ``i * S + j``
+    replays ``tasks`` with load ``i``'s submit times under scheduler seed
+    ``seeds[j]``."""
+    flat, rows, cols = make_grid_shard(submit_grid, job_submit_grid, seeds)
+
+    def point(g: GridShard):
+        tk = dataclasses.replace(tasks, submit=g.submit, job_submit=g.job_submit)
+        return tk, g.seed, None
+
+    return ShardedGrid(
+        scheduler, cfg, point, flat, rows, cols,
+        sweep_mesh() if mesh is None else mesh, chunk=chunk,
+        match_fn=match_fn, pick_fn=pick_fn, provenance=provenance,
+    )
 
 
 def sharded_grid_program(
@@ -214,28 +346,15 @@ def sharded_grid_program(
     provenance: bool = False,
 ) -> Callable[[], dict]:
     """Build (without running) the mesh-sharded (load x seed) grid
-    program — ``sweep_grid``'s point function vmapped per device under
-    ``jax.pmap``.  Returns a zero-arg runner producing the same
-    ``[L, S]`` summary dict as ``sweep_grid``."""
-    name = scheduler.lower()
-    rule = runtime.get_rule(name)  # fail fast on unknown schedulers
-    mesh = sweep_mesh() if mesh is None else mesh
-    flat, rows, cols = make_grid_shard(submit_grid, job_submit_grid, seeds)
-
-    def point(g: GridShard):
-        tk = dataclasses.replace(tasks, submit=g.submit, job_submit=g.job_submit)
-        state = runtime.simulate_fixed(
-            name, cfg, tk, g.seed, num_rounds,
-            match_fn=match_fn, pick_fn=pick_fn, provenance=provenance,
-        )
-        prov = None
-        if provenance:
-            state, prov = state
-        return _sweep.point_summary(
-            state, tk, has_queues=rule.has_queues, provenance=prov, dt=cfg.dt
-        )
-
-    return _batched_runner(point, flat, rows * cols, rows, cols, mesh)
+    program: a ``fig2_grid`` run from fresh states to exactly
+    ``num_rounds`` in ``DEFAULT_CHUNK``-round calls.  Returns a zero-arg
+    runner producing the same ``[L, S]`` summary dict as ``sweep_grid``."""
+    grid = fig2_grid(
+        scheduler, cfg, tasks, submit_grid, job_submit_grid, seeds,
+        chunk=DEFAULT_CHUNK, mesh=mesh, match_fn=match_fn, pick_fn=pick_fn,
+        provenance=provenance,
+    )
+    return lambda: grid.summary(grid.run(grid.init(), num_rounds))
 
 
 def sharded_sweep_grid(
@@ -277,9 +396,6 @@ def sharded_fault_program(
     """The Fig. 4 counterpart of ``sharded_grid_program``: the flattened
     (severity x seed) axis across the mesh, ``FaultSchedule`` leaves
     repeated per seed along the batch axis."""
-    name = scheduler.lower()
-    rule = runtime.get_rule(name)  # fail fast on unknown schedulers
-    mesh = sweep_mesh() if mesh is None else mesh
     seeds = jnp.asarray(seeds, jnp.int32)
     rows = int(jax.tree_util.tree_leaves(schedules)[0].shape[0])
     cols = int(seeds.shape[0])
@@ -290,13 +406,14 @@ def sharded_fault_program(
 
     def point(p):
         fs, seed = p
-        state = runtime.simulate_fixed(
-            name, cfg, tasks, seed, num_rounds,
-            match_fn=match_fn, pick_fn=pick_fn, faults=fs,
-        )
-        return _sweep.point_summary(state, tasks, has_queues=rule.has_queues)
+        return tasks, seed, fs
 
-    return _batched_runner(point, batch, rows * cols, rows, cols, mesh)
+    grid = ShardedGrid(
+        scheduler, cfg, point, batch, rows, cols,
+        sweep_mesh() if mesh is None else mesh, chunk=DEFAULT_CHUNK,
+        match_fn=match_fn, pick_fn=pick_fn,
+    )
+    return lambda: grid.summary(grid.run(grid.init(), num_rounds))
 
 
 def sharded_fault_sweep_grid(

@@ -126,6 +126,132 @@ def test_fault_grid_is_seed_sensitive():
     )
 
 
+def _one_shot_grid(plan):
+    """The sharded Fig. 2 grid as one program run to the whole budget, the
+    form the chunked runner replaced: ``simulate_fixed`` and
+    ``point_summary`` per point, vmapped per device under ``jax.pmap``."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from repro.simx import runtime
+
+    mesh = sxsh.sweep_mesh()
+    flat, rows, cols = sxsh.make_grid_shard(
+        plan.submit_grid, plan.job_submit_grid, plan.seeds)
+    has_queues = runtime.get_rule(plan.name).has_queues
+
+    def point(g):
+        tk = dataclasses.replace(
+            plan.tasks, submit=g.submit, job_submit=g.job_submit)
+        state = runtime.simulate_fixed(
+            plan.name, plan.cfg, tk, g.seed, plan.num_rounds,
+            match_fn=plan.match_fn, pick_fn=plan.pick_fn)
+        return sxs.point_summary(state, tk, has_queues=has_queues,
+                                 dt=plan.cfg.dt)
+
+    n_dev = int(mesh.devices.size)
+    batch, n_pad = sxsh.pad_batch(flat, rows * cols, n_dev)
+    batch = jax.tree.map(
+        lambda x: jnp.reshape(x, (n_dev, n_pad // n_dev) + x.shape[1:]), batch)
+    out = jax.pmap(jax.vmap(point), devices=list(mesh.devices.reshape(-1)))(
+        batch)
+    return {k: np.asarray(v).reshape((n_pad,) + v.shape[2:])[:rows * cols]
+            .reshape((rows, cols) + v.shape[2:]) for k, v in out.items()}
+
+
+@pytest.mark.parametrize("rule", ("megha", "sparrow"))
+def test_chunked_grid_equals_the_whole_budget_bitwise(rule):
+    """Whole chunks and an uneven remainder (235 = 3 x 64 + 43 rounds)
+    give summaries bit-identical to the grid run to its budget in one
+    program, to ``sharded_fig2_sweep`` and to the serial ``fig2_sweep``."""
+    plan = sxs.fig2_plan(rule, **FIG2)
+    assert plan.num_rounds % 64
+    grid = sxsh.fig2_grid(
+        plan.name, plan.cfg, plan.tasks, plan.submit_grid,
+        plan.job_submit_grid, plan.seeds, chunk=64, mesh=sxsh.sweep_mesh(),
+        match_fn=plan.match_fn, pick_fn=plan.pick_fn,
+    )
+    chunked = grid.summary(grid.run(grid.init(), plan.num_rounds))
+    serial, _ = _fig2_pair(rule)
+    sweep = sxsh.sharded_fig2_sweep(rule, mesh=sxsh.sweep_mesh(), **FIG2)
+    for want in (_one_shot_grid(plan), serial, sweep):
+        for key, v in want.items():
+            if key in chunked:
+                np.testing.assert_array_equal(
+                    np.asarray(chunked[key]), np.asarray(v),
+                    err_msg=f"{rule}:{key}")
+
+
+#: 2 loads x 3 seeds: 6 points, padded on 4 or 8 devices
+SMALL = dict(FIG2, loads=(0.35, 0.95))
+
+
+@pytest.mark.parametrize("rule", ("megha", "sparrow"))
+def test_every_point_equals_its_single_datacenter_runner(rule):
+    """After ``k`` chunks each grid point's state is, leaf for leaf, the
+    state ``engine.make_chunk_runner`` reaches in ``k`` chunks on that
+    load's trace and that seed."""
+    import dataclasses
+
+    from repro.simx import engine, runtime
+
+    plan = sxs.fig2_plan(rule, **SMALL)
+    chunk, k = 16, 3
+    grid = sxsh.fig2_grid(
+        plan.name, plan.cfg, plan.tasks, plan.submit_grid,
+        plan.job_submit_grid, plan.seeds, chunk=chunk,
+        mesh=sxsh.sweep_mesh(), match_fn=plan.match_fn, pick_fn=plan.pick_fn,
+    )
+    carry = grid.init()
+    for _ in range(k):
+        carry, _ = grid.runner(carry, grid.batch)
+    got = jax.tree.map(np.asarray, grid.gather(carry))
+    rule_ = runtime.get_rule(rule)
+    for i in range(len(SMALL["loads"])):
+        tk = dataclasses.replace(
+            plan.tasks, submit=plan.submit_grid[i],
+            job_submit=plan.job_submit_grid[i])
+        for j, seed in enumerate(np.asarray(plan.seeds)):
+            step = runtime.point_step(
+                rule, plan.cfg, tk, int(seed), match_fn=plan.match_fn,
+                pick_fn=plan.pick_fn)
+            runner = engine.make_chunk_runner(step, chunk)
+            state = rule_.init(plan.cfg, tk)
+            for _ in range(k):
+                state, _ = runner(state)
+            jax.tree.map(
+                lambda a, b: np.testing.assert_array_equal(
+                    a[i, j], np.asarray(b), err_msg=f"{rule} load {i} seed {j}"),
+                got, state)
+            assert int(state.rnd) == k * chunk
+
+
+def test_done_flags_turn_true_exactly_for_finished_points():
+    """A point's flag reads true in the chunk its trace finished and after,
+    false before: the flags agree with every point's state after every
+    chunk, and at some chunk the fast points are done and the slow not."""
+    plan = sxs.fig2_plan("megha", **SMALL)
+    grid = sxsh.fig2_grid(
+        plan.name, plan.cfg, plan.tasks, plan.submit_grid,
+        plan.job_submit_grid, plan.seeds, chunk=16, mesh=sxsh.sweep_mesh(),
+        match_fn=plan.match_fn,
+    )
+    carry, seen = grid.init(), set()
+    for _ in range(-(-plan.num_rounds // 16)):
+        carry, done = grid.runner(carry, grid.batch)
+        done = np.asarray(grid.gather(done))
+        st = grid.gather(carry)
+        finished = np.all(
+            np.asarray(st.task_finish) <= np.asarray(st.t)[..., None], axis=-1)
+        np.testing.assert_array_equal(done, finished)
+        assert done.shape == (len(SMALL["loads"]), SMALL["num_seeds"])
+        seen.add(int(done.sum()))
+        if done.all():
+            break
+    assert done.all() and 0 in seen and len(seen) > 2
+
+
 def test_fig2_uneven_grid_shapes():
     """15 points on any device count: outputs keep the [L, S] shape and
     carry no pad rows."""
