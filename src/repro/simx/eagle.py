@@ -52,11 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.simx import runtime as rt, spans
-from repro.simx.faults import (
-    FaultSchedule,
-    jobs_with_reservation,
-    worker_dead,
-)
+from repro.simx.faults import FaultSchedule, worker_dead
 from repro.simx.runtime import MatchFn, default_match_fn
 from repro.simx.sparrow import (
     ProbeLayout,
@@ -69,7 +65,7 @@ from repro.simx.sparrow import (
     late_bind,
     probe_mask,
     probe_window_slice,
-    queue_head_pick,
+    queue_heads,
 )
 from repro.simx.state import (
     EagleState,
@@ -285,18 +281,13 @@ def make_eagle_step(
             pend_task = jnp.isinf(task_finish) & (tasks.submit <= t)
             c, base, pending = job_counts(pend_task, job_start, job_end)
             idle = worker_finish <= t
-            pend_q = jnp.append(pending, 0)[jnp.minimum(resq, J)]  # int32[W,R]
-            active = (resq < J) & (pend_q > 0) & idle[:, None]
-            job_pick = queue_head_pick(resq, active, pick_fn, J)    # int32[W]
             # orphan rescue (see the sparrow rule): a pending short job with no
             # live reservation anywhere may be served by any idle worker
             dead = worker_dead(faults, t) if faults is not None else None
-            orphan = (
-                short_job
-                & (edge_end <= head)
-                & (pending > 0)
-                & ~jobs_with_reservation(resq, J, dead=dead)
+            job_pick, reserved, at_cap = queue_heads(
+                resq, pending, pick_fn, idle=idle, dead=dead
             )
+            orphan = short_job & (edge_end <= head) & (pending > 0) & ~reserved
             rescue = jnp.min(jnp.where(orphan, j_idx, J))
             job_pick = jnp.where(idle, jnp.minimum(job_pick, rescue), J)
             launch2, task2 = late_bind(job_pick, c, base, pending)
@@ -354,7 +345,8 @@ def make_eagle_step(
         if telemetry:
             with jax.named_scope("simx.telemetry"):
                 upd["telemetry"] = dict(
-                    launches=n_launch, sss_rejections=n_rej0 + n_rej1
+                    launches=n_launch, sss_rejections=n_rej0 + n_rej1,
+                    full_width_rounds=at_cap,
                 )
         if provenance:
             with jax.named_scope("simx.provenance"):

@@ -30,6 +30,17 @@ job's r-th pending task by a W-wide binary search of that prefix sum.  A
 round runs two [T] prefix sums (unfinished, pending) and no [T]-wide
 scatter.
 
+**Queue width** — the queues are packed to the left (compaction slides
+live entries to the front, insertion appends at ``fill``), so every
+column past the longest queue is empty.  Each per-worker queue pass
+(compaction, insertion's held test, the head pick and the orphan test)
+runs on the queues' leading ``k`` columns only, ``k`` the smallest of a
+few static widths (``QUEUE_WIDTHS``, then R) that covers the widest
+queue this round (``at_width``): the same results, at a fraction of the
+slots while the queues run short of the cap.  Under
+``vmap`` (the sweep grids) one width covers the whole batch, so the
+width's ``lax.switch`` stays a conditional (``batch_max``).
+
 Approximations vs. the event backend (beyond round quantization, see
 ``engine``): a worker whose chosen job runs out of pending tasks this
 round (more claimants than tasks) retries next round instead of popping
@@ -271,7 +282,8 @@ def insert_probes(
     also preserves the window's ascending-job order, keeping every queue
     sorted by job id).  Edges whose slot lands past R are dropped —
     returns ``(resq, n_overflow)``; merged duplicates are neither
-    inserted nor counted as overflow.
+    inserted nor counted as overflow.  The held test reads the queues at
+    the width of their longest ``fill`` (``at_width``).
     """
     W, R = resq.shape
     C = targets.shape[0]
@@ -285,8 +297,11 @@ def insert_probes(
     dup_s = dup_s.at[0].set(False)
     dup = jnp.zeros(C, jnp.bool_).at[o0].set(dup_s)
     # earlier-round duplicates: the job already queued on this worker
-    held = jnp.any(
-        resq[jnp.clip(tw0, 0, W - 1)] == jobs[:, None], axis=1
+    # (entries lie in the first ``fill`` columns: run at their width)
+    tw0_row = jnp.clip(tw0, 0, W - 1)
+    held, _ = at_width(
+        resq, jnp.max(fill),
+        lambda q: jnp.any(q[tw0_row] == jobs[:, None], axis=1),
     )
     keep = ins & ~dup & ~held
     tw = jnp.where(keep, targets, W)
@@ -297,6 +312,61 @@ def insert_probes(
     slot = fill[jnp.clip(tw, 0, W - 1)] + rank
     resq = resq.at[tw, slot].set(jobs, mode="drop")     # tw==W / slot>=R drop
     return resq, jnp.sum(keep & (slot >= R), dtype=jnp.int32)
+
+
+#: The static widths a per-worker queue pass may run at below the cap R,
+#: which always comes last (``queue_widths``).  Each one compiles the
+#: narrow passes once more, 1 to 3 s more for the TPU at 50,000 workers,
+#: so there is one: on the paper's 50,000-worker trace at load 0.8 the
+#: longest queue holds 8 to 14 entries.
+QUEUE_WIDTHS = (16,)
+
+
+def queue_widths(R: int) -> tuple[int, ...]:
+    """The static widths ``at_width`` chooses from for a cap of R slots."""
+    return tuple(k for k in QUEUE_WIDTHS if k < R) + (R,)
+
+
+@jax.custom_batching.custom_vmap
+def batch_max(x: jax.Array) -> jax.Array:
+    """The largest element of ``x``; under ``vmap``, of the whole batch's
+    ``x``, returned unbatched."""
+    return jnp.max(x)
+
+
+@batch_max.def_vmap
+def _batch_max_vmap(axis_size, in_batched, x):
+    return batch_max(x), False
+
+
+def live_width(resq: jax.Array, num_jobs: int) -> jax.Array:
+    """int32[] — one past the last queue column holding any entry
+    (``num_jobs`` = empty): every column from here on is empty in every
+    queue.  An elementwise reduction over [W, R]: no gather, no scatter."""
+    R = resq.shape[1]
+    held = jnp.any(resq < num_jobs, axis=0)                     # bool[R]
+    return jnp.max(jnp.where(held, jnp.arange(1, R + 1, dtype=jnp.int32), 0))
+
+
+def at_width(
+    resq: jax.Array, width: jax.Array, fn: Callable[[jax.Array], object]
+) -> tuple[object, jax.Array]:
+    """Run a per-worker queue pass on the queues' leading columns only.
+
+    ``fn(resq[:, :k])`` runs for the smallest static ``k`` of
+    ``queue_widths(R)`` with ``k >= width``, one ``lax.switch`` branch a
+    width; ``width`` must cover every entry, so the pass sees the same
+    entries it would see at full width.  Its results must not depend on
+    ``k``'s shape (a pass that rewrites the queues returns them at
+    ``[W, R]``).  Under ``vmap`` ``k`` covers the whole batch's widths
+    (``batch_max``): a batched index would turn the switch into a select
+    that runs every branch.  Returns ``(fn's result, k int32[])``."""
+    widths = queue_widths(resq.shape[1])
+    if len(widths) == 1:
+        return fn(resq), jnp.int32(widths[0])
+    i = jnp.sum(jnp.asarray(widths[:-1]) < batch_max(width), dtype=jnp.int32)
+    out = jax.lax.switch(i, [lambda k=k: fn(resq[:, :k]) for k in widths])
+    return out, jnp.asarray(widths, jnp.int32)[i]
 
 
 def compact_queues(
@@ -313,21 +383,27 @@ def compact_queues(
     reservations intact); live entries slide to the front preserving
     order.  Tasks must be laid out contiguously per job: ``job_start`` /
     ``job_end`` (``job_bounds``) turn one prefix sum into the per-job
-    unfinished counts.  Returns ``(resq, fill int32[W])``.
+    unfinished counts.  The pass runs at the queues' live width
+    (``at_width``).  Returns ``(resq, fill int32[W])``.
     """
     W, R = resq.shape
     num_jobs = job_start.shape[0]
     unfinished = job_counts(task_finish > t, job_start, job_end)[2]
     unfinished = jnp.append(unfinished, 0)                      # J = empty
-    live = (resq < num_jobs) & (unfinished[jnp.minimum(resq, num_jobs)] > 0)
-    pos = jnp.cumsum(live, axis=1) - 1
-    w_rows = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[:, None], (W, R))
-    out = (
-        jnp.full((W, R), num_jobs, jnp.int32)
-        .at[w_rows, jnp.where(live, pos, R)]
-        .set(resq, mode="drop")
-    )
-    return out, jnp.sum(live, axis=1, dtype=jnp.int32)
+
+    def compact(q):
+        k = q.shape[1]
+        live = (q < num_jobs) & (unfinished[jnp.minimum(q, num_jobs)] > 0)
+        pos = jnp.cumsum(live, axis=1) - 1
+        w_rows = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[:, None], (W, k))
+        out = (
+            jnp.full((W, R), num_jobs, jnp.int32)   # columns k: hold no entry
+            .at[w_rows, jnp.where(live, pos, R)]
+            .set(q, mode="drop")
+        )
+        return out, jnp.sum(live, axis=1, dtype=jnp.int32)
+
+    return at_width(resq, live_width(resq, num_jobs), compact)[0]
 
 
 def queue_head_pick(
@@ -349,6 +425,41 @@ def queue_head_pick(
     slot = jnp.argmax(picked, axis=1)
     head = jnp.take_along_axis(resq, slot[:, None], axis=1)[:, 0]
     return jnp.where(jnp.any(picked, axis=1), head, num_jobs)
+
+
+def queue_heads(
+    resq: jax.Array,
+    pending: jax.Array,
+    match_fn: MatchFn,
+    *,
+    idle: jax.Array | None = None,
+    dead: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Late binding's queue passes, shared by the sparrow and eagle rules.
+
+    An entry is active while its job has pending tasks (``pending
+    int32[J]``; with ``idle``, only on idle workers).  Returns
+    ``(job_pick int32[W], reserved bool[J], at_cap int32[])``: each
+    worker's head-of-queue job (``queue_head_pick``), the jobs holding an
+    entry on a live worker (``jobs_with_reservation``), and 1 where the
+    passes ran at the cap R, else 0 (the ``full_width_rounds`` counter;
+    under ``vmap``, for the batch's widest queue).  The width is read
+    here, after this round's insertion has appended to the queues
+    (``at_width``)."""
+    J = pending.shape[0]
+    pend_j = jnp.append(pending, 0)                             # J = empty
+
+    def heads(q):
+        active = (q < J) & (pend_j[jnp.minimum(q, J)] > 0)
+        if idle is not None:
+            active = active & idle[:, None]
+        return (
+            queue_head_pick(q, active, match_fn, J),
+            jobs_with_reservation(q, J, dead=dead),
+        )
+
+    (job_pick, reserved), k = at_width(resq, live_width(resq, J), heads)
+    return job_pick, reserved, (k == resq.shape[1]).astype(jnp.int32)
 
 
 @jax.tree_util.register_dataclass
@@ -451,19 +562,15 @@ def make_sparrow_step(
         with jax.named_scope("simx.sparrow.bind"):
             pend_task = jnp.isinf(task_finish0) & (tasks.submit <= t)   # bool[T]
             c, base, pending = job_counts(pend_task, job_start, job_end)
-            pend_q = jnp.append(pending, 0)[jnp.minimum(resq, J)]      # int32[W,R]
-            active = (resq < J) & (pend_q > 0)                          # bool[W,R]
-            job_pick = queue_head_pick(resq, active, match_fn, J)       # int32[W]
             # orphan rescue: an inserted pending job with no live reservation
             # anywhere (all probes dropped on full queues, or — under faults —
             # every probed worker currently dead) may be served by any idle
             # worker (dead workers never serve: worker_finish holds recovery)
             dead = worker_dead(faults, t) if faults is not None else None
-            orphan = (
-                (edge_end <= head)
-                & (pending > 0)
-                & ~jobs_with_reservation(resq, J, dead=dead)
+            job_pick, reserved, at_cap = queue_heads(
+                resq, pending, match_fn, dead=dead
             )
+            orphan = (edge_end <= head) & (pending > 0) & ~reserved
             rescue = jnp.min(jnp.where(orphan, j_idx, J))
             job_pick = jnp.minimum(job_pick, rescue)
             launch, task_pick = late_bind(
@@ -489,7 +596,10 @@ def make_sparrow_step(
             )
         if telemetry:
             with jax.named_scope("simx.telemetry"):
-                upd["telemetry"] = dict(launches=jnp.sum(launch, dtype=jnp.int32))
+                upd["telemetry"] = dict(
+                    launches=jnp.sum(launch, dtype=jnp.int32),
+                    full_width_rounds=at_cap,
+                )
         if provenance:
             with jax.named_scope("simx.provenance"):
                 # attempt = a scheduler acted on the job this round: its probes

@@ -28,9 +28,10 @@ The round-step contract (see ``runtime.compose_step``): a rule's dispatch
 MAY return a ``"telemetry"`` key — a dict of per-round int32 scalar
 counters (``launches`` expected of every rule, plus rule-specific extras:
 megha ``view_repairs``, eagle ``sss_rejections``, pigeon
-``reserve_hits``) — and the runtime adds the per-round deltas of the
-shared ``CoreState`` counters (messages, probes, inconsistencies, lost,
-and the reservation-queue health counters for ``QueueState`` rules).
+``reserve_hits``, sparrow and eagle ``full_width_rounds``) — and the
+runtime adds the per-round deltas of the shared ``CoreState`` counters
+(messages, probes, inconsistencies, lost, and the reservation-queue
+health counters for ``QueueState`` rules).
 With telemetry disabled the key is never built and the step compiles to
 exactly today's program — final states are pinned bitwise-identical by
 ``tests/test_simx_telemetry.py``.

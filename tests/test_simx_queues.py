@@ -16,7 +16,12 @@
   threshold could select more on tied uniforms);
 * a deliberately undersized cap overflows (counted), yet completes the
   trace with parity-close delays (orphan rescue preserves liveness);
-* carried state is independent of the trace length.
+* carried state is independent of the trace length;
+* the queue passes run at the queues' live width (``at_width``): the
+  width helper covers the widest queue (under ``vmap``, the batch's), and
+  sparrow and eagle reach the retired full-width passes' final state bit
+  for bit — clean, under crash waves, with an undersized cap, across the
+  widths, streamed and vmapped.
 """
 
 import dataclasses
@@ -26,8 +31,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.simx import SimxConfig, empty_schedule, engine, export_workload
+from repro.core.base import Job
+from repro.simx import (
+    SimxConfig,
+    TelemetryConfig,
+    empty_schedule,
+    engine,
+    export_workload,
+)
 from repro.simx import eagle as simx_eagle
+from repro.simx import runtime as simx_runtime
 from repro.simx import sparrow as simx_sparrow
 from repro.simx import sweep as simx_sweep
 from repro.simx.state import (
@@ -35,7 +48,9 @@ from repro.simx.state import (
     init_sparrow_state,
     probe_edge_layout,
 )
-from repro.workload.synth import synthetic_trace
+from repro.simx.stream import run_steady_state
+from repro.workload.synth import ReplayArrivals, synthetic_trace
+from repro.workload.traces import Workload
 
 
 # ---------------------------------------------------------------------------
@@ -629,3 +644,363 @@ def test_sparrow_queue_pick_via_pallas_kernel_matches_ref(small):
     )
     assert jnp.array_equal(ref_run.task_finish, pal_run.task_finish)
     assert jnp.array_equal(ref_run.worker_finish, pal_run.worker_finish)
+
+
+# ---------------------------------------------------------------------------
+# the queue passes at the queues' live width
+# ---------------------------------------------------------------------------
+
+
+def packed_queues(fill, num_jobs, R, seed=0):
+    """int32[W, R] queues packed to the left: row w holds ``fill[w]``
+    random job ids, then the empty sentinel ``num_jobs``."""
+    rng = np.random.default_rng(seed)
+    fill = np.asarray(fill)
+    q = rng.integers(0, num_jobs, (fill.size, R))
+    return jnp.asarray(
+        np.where(np.arange(R)[None, :] < fill[:, None], q, num_jobs), jnp.int32
+    )
+
+
+def test_queue_widths_end_at_the_cap(monkeypatch):
+    assert simx_sparrow.queue_widths(64) == (16, 64)
+    assert simx_sparrow.queue_widths(16) == (16,)
+    assert simx_sparrow.queue_widths(3) == (3,)
+    monkeypatch.setattr(simx_sparrow, "QUEUE_WIDTHS", (8, 16, 32))
+    assert simx_sparrow.queue_widths(64) == (8, 16, 32, 64)
+    assert simx_sparrow.queue_widths(20) == (8, 16, 20)
+
+
+@pytest.mark.parametrize("narrow", [None, (8, 16, 32)], ids=["default", "three"])
+@pytest.mark.parametrize(
+    "longest", [0, 1, 8, 9, 16, 17, 32, 33, 63, 64, "hole"]
+)
+def test_width_helper_covers_the_widest_queue(longest, narrow, monkeypatch):
+    """On packed queues the live width is the longest queue, and
+    ``at_width`` runs its pass at the smallest static width covering it:
+    all-empty queues take the smallest width, one full row takes R.  A
+    lone entry past a gap (a hole) still counts to its own column.  The
+    helper holds for any set of narrow widths."""
+    if narrow is not None:
+        monkeypatch.setattr(simx_sparrow, "QUEUE_WIDTHS", narrow)
+    J, W, R = 50, 40, 64
+    rng = np.random.default_rng(7)
+    if longest == "hole":
+        resq = jnp.full((W, R), J, jnp.int32).at[3, 40].set(5)
+        want_width = 41
+    else:
+        fill = rng.integers(0, min(longest, 8) + 1, W)
+        fill[rng.integers(0, W)] = longest
+        resq = packed_queues(fill, J, R, seed=longest)
+        want_width = longest
+    width = simx_sparrow.live_width(resq, J)
+    assert int(width) == want_width
+    ran_at, k = simx_sparrow.at_width(resq, width, lambda q: jnp.int32(q.shape[1]))
+    want_k = min(w for w in simx_sparrow.queue_widths(R) if w >= want_width)
+    assert int(ran_at) == int(k) == want_k
+    if longest == 0:
+        assert want_k == simx_sparrow.queue_widths(R)[0]
+    if longest == R:
+        assert want_k == R
+    # the pass sees every entry it would see at full width
+    counts, _ = simx_sparrow.at_width(
+        resq, width, lambda q: jnp.sum(q < J, axis=1, dtype=jnp.int32)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.asarray(jnp.sum(resq < J, axis=1))
+    )
+
+
+def test_at_width_takes_one_width_a_batch():
+    """Under ``vmap`` — nested too, as the sweep grids nest it — every
+    point's pass runs at one width, the smallest covering the widest queue
+    of the whole batch (``batch_max``), so the switch index stays
+    unbatched and the program keeps a conditional."""
+    J, W, R = 30, 12, 64
+    fills = [[3, 0], [20, 5], [1, 9]]
+    stack = jnp.stack([
+        jnp.stack([packed_queues(np.full(W, f), J, R, seed=f) for f in row])
+        for row in fills
+    ])                                                  # int32[3, 2, W, R]
+
+    def k_of(q):
+        return simx_sparrow.at_width(
+            q, simx_sparrow.live_width(q, J), lambda p: jnp.int32(p.shape[1])
+        )[1]
+
+    np.testing.assert_array_equal(np.asarray(jax.vmap(k_of)(stack[0])), [16, 16])
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(jax.vmap(k_of))(stack)), np.full((3, 2), R)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(simx_sparrow.batch_max)(jnp.arange(6))), np.full(6, 5)
+    )
+    text = jax.jit(jax.vmap(lambda q: simx_sparrow.at_width(
+        q, simx_sparrow.live_width(q, J), lambda p: jnp.sum(p < J, axis=1))[0]
+    )).lower(stack[1]).compile().as_text()
+    assert " conditional(" in text
+
+
+def retired_compact_queues(resq, task_finish, t, job_start, job_end):
+    """The full-width compaction the rules ran before the queue passes
+    followed the live width: every pass over all R columns."""
+    W, R = resq.shape
+    num_jobs = job_start.shape[0]
+    unfinished = simx_sparrow.job_counts(task_finish > t, job_start, job_end)[2]
+    unfinished = jnp.append(unfinished, 0)
+    live = (resq < num_jobs) & (unfinished[jnp.minimum(resq, num_jobs)] > 0)
+    pos = jnp.cumsum(live, axis=1) - 1
+    w_rows = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[:, None], (W, R))
+    out = (
+        jnp.full((W, R), num_jobs, jnp.int32)
+        .at[w_rows, jnp.where(live, pos, R)]
+        .set(resq, mode="drop")
+    )
+    return out, jnp.sum(live, axis=1, dtype=jnp.int32)
+
+
+def retired_insert_probes(resq, fill, targets, jobs, ins):
+    """Insertion with the held test over all R columns of each target's
+    queue, as before the passes followed the live width."""
+    W, R = resq.shape
+    C = targets.shape[0]
+    c_row = jnp.arange(C, dtype=jnp.int32)
+    tw0 = jnp.where(ins, targets, W)
+    o0 = jnp.argsort(tw0, stable=True)
+    st0, sj0 = tw0[o0], jobs[o0]
+    dup_s = (st0 == jnp.roll(st0, 1)) & (sj0 == jnp.roll(sj0, 1))
+    dup_s = dup_s.at[0].set(False)
+    dup = jnp.zeros(C, jnp.bool_).at[o0].set(dup_s)
+    held = jnp.any(resq[jnp.clip(tw0, 0, W - 1)] == jobs[:, None], axis=1)
+    keep = ins & ~dup & ~held
+    tw = jnp.where(keep, targets, W)
+    order = jnp.argsort(tw, stable=True)
+    stw = tw[order]
+    first = jnp.searchsorted(stw, stw, side="left").astype(jnp.int32)
+    rank = jnp.zeros(C, jnp.int32).at[order].set(c_row - first)
+    slot = fill[jnp.clip(tw, 0, W - 1)] + rank
+    resq = resq.at[tw, slot].set(jobs, mode="drop")
+    return resq, jnp.sum(keep & (slot >= R), dtype=jnp.int32)
+
+
+def retired_queue_heads(resq, pending, match_fn, *, idle=None, dead=None):
+    """Late binding's queue passes as the rules ran them inline at full
+    width: the ``[W, R]`` pending gather, the head pick's rank-and-select
+    and the orphan test's ``pred`` scatter-max."""
+    W, _ = resq.shape
+    J = pending.shape[0]
+    pend_q = jnp.append(pending, 0)[jnp.minimum(resq, J)]
+    active = (resq < J) & (pend_q > 0)
+    if idle is not None:
+        active = active & idle[:, None]
+    picked = match_fn(active, jnp.ones(W, jnp.int32)) == 0
+    head = jnp.take_along_axis(resq, jnp.argmax(picked, axis=1)[:, None], axis=1)
+    job_pick = jnp.where(jnp.any(picked, axis=1), head[:, 0], J)
+    exists = resq < J
+    if dead is not None:
+        exists = exists & ~dead[:, None]
+    reserved = (
+        jnp.zeros(J, jnp.bool_).at[resq.ravel()].max(exists.ravel(), mode="drop")
+    )
+    return job_pick, reserved, jnp.int32(1)
+
+
+@pytest.mark.parametrize("longest", [5, 16, 17, 40, 64])
+def test_insert_probes_matches_full_width_held_test(longest):
+    """Insertion equals the retired full-width held test when edges repeat
+    a job already queued deep in the target's queue (eagle's SSS re-routes
+    make such repeats): merged, not inserted, at every width."""
+    rng = np.random.default_rng(longest)
+    J, W, R, C = 120, 24, 64, 80
+    fill = rng.integers(0, min(longest, 12) + 1, W)
+    fill[:3] = longest
+    q = np.full((W, R), J, np.int64)
+    for w in range(W):
+        q[w, : fill[w]] = np.sort(rng.choice(J, fill[w], replace=False))
+    jobs = np.sort(rng.integers(0, J, C))
+    targets = rng.integers(0, W, C)
+    deep = rng.choice(C, C // 4, replace=False)         # repeats of queued jobs
+    for i, e in enumerate(deep):                       # the last entry too
+        w = i % 3
+        col = longest - 1 if i < 3 else rng.integers(0, longest)
+        targets[e], jobs[e] = w, q[w, col]
+    order = np.argsort(jobs, kind="stable")
+    args = (jnp.asarray(q, jnp.int32), jnp.asarray(fill, jnp.int32),
+            jnp.asarray(targets[order], jnp.int32),
+            jnp.asarray(jobs[order], jnp.int32), jnp.arange(C) < C - 7)
+    new_q, new_over = simx_sparrow.insert_probes(*args)
+    old_q, old_over = retired_insert_probes(*args)
+    np.testing.assert_array_equal(np.asarray(new_q), np.asarray(old_q))
+    assert int(new_over) == int(old_over)
+
+
+@pytest.fixture
+def retired_queue_passes(monkeypatch):
+    """``install()`` rebuilds sparrow and eagle on the retired full-width
+    queue passes: compaction, insertion and late binding's head pick and
+    orphan test.  Also returns the list of patched calls made since."""
+    calls = []
+
+    def compact(*a):
+        calls.append("compact")
+        return retired_compact_queues(*a)
+
+    def insert(*a):
+        calls.append("insert")
+        return retired_insert_probes(*a)
+
+    def heads(*a, **k):
+        calls.append("heads")
+        return retired_queue_heads(*a, **k)
+
+    def install():
+        for mod in (simx_sparrow, simx_eagle):
+            monkeypatch.setattr(mod, "compact_queues", compact)
+            monkeypatch.setattr(mod, "insert_probes", insert)
+            monkeypatch.setattr(mod, "queue_heads", heads)
+
+    return install, calls
+
+
+def burst_tasks(num_jobs, num_workers, seed, squeeze=0.05):
+    """A trace whose arrivals are squeezed into a burst, so that every
+    worker's queue grows long; a quarter of the jobs are eagle's long
+    jobs."""
+    wl = synthetic_trace(num_jobs=num_jobs, tasks_per_job=8, load=1.0,
+                         num_workers=num_workers, seed=seed)
+    tasks = export_workload(wl)
+    est = np.asarray(tasks.job_est).copy()
+    est[::4] = 99.0
+    return dataclasses.replace(
+        tasks, submit=tasks.submit * squeeze,
+        job_submit=tasks.job_submit * squeeze, job_est=jnp.asarray(est),
+    )
+
+
+def assert_states_equal(new, old):
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(new), jax.tree_util.tree_leaves(old)
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path)
+        )
+
+
+@pytest.mark.parametrize("case", ["clean", "crashes", "undersized", "crossing"])
+@pytest.mark.parametrize("mod", [simx_sparrow, simx_eagle])
+def test_live_width_matches_full_width_bitwise(mod, case, retired_queue_passes):
+    """Sparrow and eagle reach the retired full-width queue passes' final
+    state, every field and counter, bit for bit: on a plain trace, under a
+    wave of worker crashes, with a cap so small that queues fill to R and
+    overflow, and on a burst whose queues cross from the narrow width to
+    the cap R = 64 and back."""
+    if case == "crossing":
+        tasks = burst_tasks(80, 16, seed=4)
+        cfg = SimxConfig(num_workers=16, dt=0.02, reserve_cap=64,
+                         long_threshold=10.0)
+    else:
+        wl = synthetic_trace(num_jobs=24, tasks_per_job=16, load=0.9,
+                             num_workers=32, seed=6)
+        tasks = export_workload(wl)
+        est = np.asarray(tasks.job_est).copy()
+        est[::4] = 99.0
+        tasks = dataclasses.replace(tasks, job_est=jnp.asarray(est))
+        cap = 3 if case == "undersized" else 40
+        cfg = SimxConfig(num_workers=32, dt=0.02, reserve_cap=cap,
+                         long_threshold=10.0)
+    W = cfg.num_workers
+    faults = None
+    if case == "crashes":
+        rng = np.random.default_rng(5)
+        down = np.full(W, np.inf, np.float32)
+        hit = rng.permutation(W)[: W // 2]
+        down[hit] = np.linspace(0.2, 3.0, hit.size).astype(np.float32)
+        faults = empty_schedule(W, cfg.num_gms).replace(
+            worker_down=jnp.asarray(down), worker_up=jnp.asarray(down + 0.1)
+        )
+    rounds = engine.estimate_rounds(cfg, tasks, slack=4.0)
+    new, tl = simx_runtime.simulate_fixed(
+        mod.RULE.name, cfg, tasks, 3, rounds, faults=faults,
+        telemetry=TelemetryConfig(stride=1),
+    )
+    at_cap = int(np.sum(tl.series["full_width_rounds"]))
+    if case == "crossing":
+        assert 0 < at_cap < rounds // 2, at_cap
+    if case == "undersized":
+        assert at_cap == rounds                     # R = 3: no narrow width
+        assert int(new.res_overflow) > 0
+    if case in ("clean", "crashes"):
+        assert at_cap == 0
+    if case == "crashes":
+        assert int(new.lost) > 0
+    install, calls = retired_queue_passes
+    install()
+    old = mod.simulate_fixed(cfg, tasks, 3, rounds, faults=faults)
+    assert {"compact", "insert", "heads"} <= set(calls)
+    assert_states_equal(new, old)
+
+
+@pytest.mark.parametrize("rule", ["sparrow", "eagle"])
+def test_live_width_matches_full_width_streamed(rule, retired_queue_passes):
+    """The streaming window (``make_<rule>_step(layout=...)``, the queues
+    remapped on the host between refills) replays the retired full-width
+    queue passes bit for bit: delays, counters and every refill's
+    ledger."""
+    wl = synthetic_trace(num_jobs=40, tasks_per_job=8, load=1.0,
+                         num_workers=16, seed=3)
+    wl = Workload(name="burst", jobs=[                 # arrivals in a burst
+        Job(job_id=j.job_id, submit_time=j.submit_time * 0.05,
+            durations=list(j.durations))
+        for j in wl.jobs
+    ])
+    # pick_fn given: a fresh segment, not the memoized default one
+    kw = dict(window_jobs=32, window_tasks=256, rounds_per_refill=16,
+              reserve_cap=32, dt=0.02, seed=0,
+              pick_fn=simx_runtime.default_match_fn(block_rows=1),
+              telemetry=TelemetryConfig(stride=1))
+    new = run_steady_state(rule, ReplayArrivals(wl), 16, **kw)
+    at_cap = np.asarray(new.timeline.series["full_width_rounds"])
+    assert 0 < at_cap.sum() < at_cap.size          # both widths ran
+    install, calls = retired_queue_passes
+    install()
+    old = run_steady_state(rule, ReplayArrivals(wl), 16, **kw)
+    assert {"compact", "insert", "heads"} <= set(calls)
+    assert new.tasks_completed == wl.num_tasks
+    assert np.array_equal(new.delays, old.delays)
+    assert (new.tasks_completed, new.probes, new.messages, new.rounds) == (
+        old.tasks_completed, old.probes, old.messages, old.rounds)
+    for k in new.series:
+        assert np.array_equal(new.series[k], old.series[k], equal_nan=True), k
+    assert new.refills == old.refills
+
+
+@pytest.mark.parametrize("rule", ["sparrow", "eagle"])
+def test_live_width_matches_full_width_vmapped(rule, retired_queue_passes):
+    """The sweep grid (``sweep_grid``: a vmap over loads of a vmap over
+    seeds) gives the retired full-width passes' summaries bit for bit, and
+    its optimized program keeps the width conditionals of compaction,
+    insertion's held test and late binding: one width a batch, so no
+    select runs every branch."""
+    kw = dict(loads=(0.5, 0.95), num_seeds=2, num_workers=64, num_jobs=16,
+              tasks_per_job=24, dt=0.02, trace_seed=3, reserve_cap=64,
+              long_threshold=10.0)
+    plan = simx_sweep.fig2_plan(rule, **kw)
+    args = (plan.name, plan.cfg, plan.tasks, plan.submit_grid,
+            plan.job_submit_grid, plan.seeds, plan.num_rounds)
+    new = simx_sweep.sweep_grid(*args, pick_fn=plan.pick_fn)
+    text = jax.jit(jax.vmap(jax.vmap(
+        lambda sub, seed: simx_runtime.simulate_fixed(
+            plan.name, plan.cfg,
+            dataclasses.replace(plan.tasks, submit=sub), seed, 4,
+            pick_fn=plan.pick_fn,
+        ),
+        in_axes=(None, 0)), in_axes=(0, None),
+    )).lower(plan.submit_grid, plan.seeds).compile().as_text()
+    assert text.count(" conditional(") == 3
+    install, calls = retired_queue_passes
+    install()
+    old = simx_sweep.sweep_grid(*args, pick_fn=plan.pick_fn)
+    assert {"compact", "insert", "heads"} <= set(calls)
+    assert int(np.min(new["tasks_done"])) == plan.tasks.num_tasks
+    for k in new:
+        np.testing.assert_array_equal(np.asarray(new[k]), np.asarray(old[k]), err_msg=k)

@@ -6,6 +6,8 @@ counter of ``repro.simx.spans``.
   and provenance under ``simx.telemetry`` / ``simx.provenance``;
 * the scopes are metadata only: the optimized program is the same with
   ``jax.named_scope`` made a no-op;
+* the queue passes that run at the queues' live width keep their rule's
+  section scopes inside the ``conditional`` branches that hold them;
 * ``simx.build`` times the rule builders (the compiles inside it counted
   apart), the compile counter names the chunk runner ``simx_chunk`` and
   can stop at its last call, and reading the runner's optimized HLO
@@ -13,8 +15,10 @@ counter of ``repro.simx.spans``.
 """
 
 import contextlib
+import dataclasses
 import random
 import re
+from collections import defaultdict
 
 import jax
 import pytest
@@ -114,6 +118,55 @@ def test_scopes_leave_the_optimized_program_alone(mixed, name, monkeypatch):
     monkeypatch.setattr(jax, "named_scope",
                         lambda _name: contextlib.nullcontext())
     assert _optimized(mixed, name) == scoped
+
+
+_COMP = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_INST = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def innermost_scope(op_name: str):
+    return next((seg for seg in reversed(op_name.split("/"))
+                 if seg.startswith("simx.")), None)
+
+
+@pytest.mark.parametrize("name", ["sparrow", "eagle"])
+def test_width_branches_keep_their_section_scopes(mixed, name):
+    """With a cap wide enough for a narrow width (R = 64), the optimized
+    runner holds one ``conditional`` for each of compaction, insertion's
+    held test and late binding's queue passes.  Each conditional carries
+    its section's ``simx.<rule>.<section>`` scope, and so does every op in
+    its branches that has a ``simx.`` scope of its own, so the
+    benchmark's stage split still places them (an op with none, such as a
+    branch's parameters, takes its container's)."""
+    cfg, tasks = mixed
+    cfg = dataclasses.replace(cfg, reserve_cap=64)
+    rule = rt.get_rule(name)
+    step = rule.build_step(cfg, tasks, jax.random.PRNGKey(0))
+    text = engine.make_chunk_runner(step, 2).lower(
+        rule.init(cfg, tasks)).compile().as_text()
+    body, conds, comp = defaultdict(list), [], None
+    for line in text.splitlines():
+        if m := _COMP.match(line):
+            comp = m.group(1)
+        elif (m := _INST.match(line)) and comp is not None:
+            body[comp].append(m.group(2))
+            if " conditional(" in m.group(2):
+                conds.append(m.group(2))
+    sections = set()
+    for inst in conds:
+        scope = innermost_scope(_OP_NAME.search(inst).group(1))
+        assert scope in {f"simx.{name}.{s}" for s in ("compact", "insert", "bind")}
+        sections.add(scope)
+        scoped = 0
+        for branch in _BRANCHES.search(inst).group(1).split(","):
+            for op in body[branch.strip().lstrip("%")]:
+                if (m := _OP_NAME.search(op)) and innermost_scope(m.group(1)):
+                    assert innermost_scope(m.group(1)) == scope, op
+                    scoped += 1
+        assert scoped > 0
+    assert len(conds) == len(sections) == 3
 
 
 def test_build_span_and_compile_counter(mixed):

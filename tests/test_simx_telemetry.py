@@ -15,9 +15,13 @@
   overhead columns;
 * the engine surface: ``simulate_workload(..., telemetry=...)`` attaches
   a ``Timeline`` without perturbing the run, and ``to_chrome_trace()``
-  round-trips through JSON as pure counter/metadata events.
+  round-trips through JSON as pure counter/metadata events;
+* the ``full_width_rounds`` counter: 1 in the rounds whose queue passes
+  ran at the cap, because the longest reservation queue outgrew the
+  narrow width.
 """
 
+import dataclasses
 import json
 
 import jax
@@ -33,6 +37,7 @@ from repro.simx import (
     export_workload,
     runtime,
 )
+from repro.simx import sparrow as simx_sparrow
 from repro.simx import sweep as simx_sweep
 from repro.sim.simulator import run_simulation
 from repro.workload.synth import synthetic_trace
@@ -237,3 +242,37 @@ def test_engine_timeline_and_chrome_trace():
     assert comp == sorted(comp)
     ts = [e["ts"] for e in evs if e["name"] == "completed"]
     np.testing.assert_allclose(ts, np.asarray(tl.t, np.float64) * 1e6, rtol=1e-6)
+
+
+def test_full_width_rounds_counts_rounds_at_the_cap():
+    """Sparrow's ``full_width_rounds`` is 1 in exactly the rounds whose
+    longest queue after insertion, counted in numpy from the round's
+    state, outgrows the narrow width, so that late binding's queue passes
+    ran at the cap R; a burst of arrivals drives it across both widths.
+    A decimated window sums it like every other counter."""
+    wl = synthetic_trace(num_jobs=80, tasks_per_job=8, load=1.0,
+                         num_workers=16, seed=4)
+    tasks = export_workload(wl)
+    tasks = dataclasses.replace(                        # arrivals in a burst
+        tasks, submit=tasks.submit * 0.05, job_submit=tasks.job_submit * 0.05
+    )
+    cfg = SimxConfig(num_workers=16, dt=0.02, reserve_cap=64)
+    narrow = simx_sparrow.queue_widths(64)[-2]
+    rounds = 240
+    step = jax.jit(runtime.point_step("sparrow", cfg, tasks, 0, telemetry=True))
+    state = runtime.init_carry("sparrow", cfg, tasks)
+    got, want = [], []
+    for _ in range(rounds):
+        state, counters = step(state)
+        longest = int((np.asarray(state.resq) < tasks.num_jobs).sum(axis=1).max())
+        want.append(int(longest > narrow))
+        got.append(int(counters["full_width_rounds"]))
+    assert got == want
+    assert 0 < sum(want) < rounds
+    _, tl = runtime.simulate_fixed(
+        "sparrow", cfg, tasks, 0, rounds, telemetry=TelemetryConfig(stride=4)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(tl.series["full_width_rounds"]),
+        np.asarray(got).reshape(-1, 4).sum(axis=1),
+    )
