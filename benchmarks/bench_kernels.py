@@ -1,6 +1,6 @@
 """Kernel microbenchmarks: the Pallas match kernel (interpret mode on CPU —
-wall-times are NOT TPU times; the derived column carries bytes and the
-roofline-relevant sizes) and the serving-engine placement round."""
+wall-times are NOT TPU times; the derived column carries the bytes moved
+and the problem sizes) and the serving-engine placement round."""
 
 from __future__ import annotations
 

@@ -1,8 +1,9 @@
-"""Serve a small model with batched requests, scheduled by Megha.
+"""Place batched inference requests on a fleet's decode slots with Megha.
 
 Demonstrates the paper's architecture as the control plane of an inference
 fleet: 2 pods x 16 decode slots, 2 GM frontends with eventually-consistent
-views, real KV-cache decode on the slots (tiny qwen-family model).
+views of slot occupancy.  Each request holds its slot for its generation
+length in engine ticks (no model runs).
 
     PYTHONPATH=src python examples/serve_cluster.py
 """
@@ -14,8 +15,7 @@ from repro.launch.serve import main
 if __name__ == "__main__":
     if len(sys.argv) == 1:
         sys.argv += [
-            "--arch", "qwen15_05b", "--requests", "120",
-            "--pods", "2", "--slots", "16", "--frontends", "2",
-            "--real-decode",
+            "--requests", "120", "--pods", "2", "--slots", "16",
+            "--frontends", "2",
         ]
     main()

@@ -1,3 +1,2 @@
-# The paper's primary contribution — implement the SYSTEM here
-# (scheduler, optimizer, data path, serving loop, etc.) in the
-# host framework. Add sibling subpackages for substrates.
+"""The discrete-event scheduler simulation: Megha, its baselines, the event
+loop and the delay metrics."""

@@ -1,14 +1,13 @@
-"""Serving driver: Megha-scheduled continuous-batching decode.
+"""Serving driver: Megha-scheduled request placement on decode slots.
 
-  python -m repro.launch.serve --arch qwen15_05b --requests 200 --pods 2 \
-      --slots 16 --frontends 2 [--real-decode]
+  python -m repro.launch.serve --requests 200 --pods 2 --slots 16 \
+      --frontends 2
 
 Slots are continuous-batching lanes; the Megha engine (frontends = GMs with
 eventually-consistent fleet views, pod controllers = LMs with ground truth)
-places each request on a lane.  With --real-decode, one pod's lanes run an
-actual tiny-model decode (one token per engine tick per active lane),
-demonstrating the full path: request -> Megha placement -> KV-cache decode
--> completion -> slot reuse.
+places each request on a lane and holds it for its generation length in
+ticks: request -> Megha placement -> simulated decode -> completion -> slot
+reuse.  No model runs; the hold times stand in for decode work.
 """
 
 from __future__ import annotations
@@ -16,53 +15,19 @@ from __future__ import annotations
 import argparse
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, smoke_config
-from repro.models import decode as D
-from repro.models import model as M
-from repro.models.schema import init_params
 from repro.serve.engine import MeghaServeEngine, Request
-
-
-class ModelRunner:
-    """Real decode compute for one pod's slots (continuous batching)."""
-
-    def __init__(self, arch: str, slots: int, max_len: int = 64, seed: int = 0):
-        self.cfg = smoke_config(get_config(arch))
-        self.slots = slots
-        self.params = init_params(M.model_schema(self.cfg), jax.random.PRNGKey(seed))
-        self.cache = D.init_cache(self.cfg, slots, max_len)
-        self.tokens = jnp.ones((slots, 1), jnp.int32)
-        self.pos = 0
-        self.max_len = max_len
-        self._step = jax.jit(
-            lambda p, c, b: D.decode_step(p, c, b, self.cfg), donate_argnums=1
-        )
-
-    def tick(self) -> None:
-        if self.pos >= self.max_len:
-            return
-        logits, self.cache = self._step(
-            self.params, self.cache,
-            {"tokens": self.tokens, "pos": jnp.asarray(self.pos, jnp.int32)},
-        )
-        self.tokens = jnp.argmax(logits, -1, keepdims=True).astype(jnp.int32)
-        self.pos += 1
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen15_05b")
     ap.add_argument("--requests", type=int, default=200)
     ap.add_argument("--pods", type=int, default=2)
     ap.add_argument("--slots", type=int, default=16)
     ap.add_argument("--frontends", type=int, default=2)
     ap.add_argument("--mean-gen", type=int, default=12)
     ap.add_argument("--arrival", type=float, default=8.0, help="requests/tick")
-    ap.add_argument("--real-decode", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
@@ -71,7 +36,6 @@ def main() -> None:
         num_frontends=args.frontends, num_pods=args.pods,
         slots_per_pod=args.slots, max_batch=args.slots * args.pods,
     )
-    runner = ModelRunner(args.arch, args.slots) if args.real_decode else None
 
     t0 = time.time()
     rid = 0
@@ -83,8 +47,6 @@ def main() -> None:
         ])
         rid += n
         eng.tick()
-        if runner is not None:
-            runner.tick()
     stats = eng.run_until_drained()
     dt = time.time() - t0
     s = stats.summary()
@@ -93,9 +55,6 @@ def main() -> None:
     print(f"placement: inconsistency_ratio={s['inconsistency_ratio']:.4f} "
           f"repartitions={s['repartitions']} "
           f"queue delay mean={s['mean_queue_delay']:.2f} p95={s['p95_queue_delay']:.2f} ticks")
-    if runner is not None:
-        print(f"real decode: {runner.pos} tokens/lane on {args.slots} lanes "
-              f"({args.arch} smoke config)")
 
 
 if __name__ == "__main__":

@@ -15,9 +15,8 @@ paper: the pod rejects, piggybacks fresh state, and the frontend retries at
 the head of its queue.  Freed *borrowed* slots return to their owner only at
 the next heartbeat (§3.4).
 
-The engine advances in ticks (one tick ~ one decode macro-step).  A
-``ModelRunner`` can attach real decode compute to one pod's slots; without
-one, slot hold times are simulated from request generation lengths.
+The engine advances in ticks (one tick ~ one decode macro-step).  Slot
+hold times are simulated from request generation lengths.
 """
 
 from __future__ import annotations

@@ -9,8 +9,8 @@ runs those batch axes serially on one device; this module lays them
 across a 1-D ``"grid"`` mesh axis instead:
 
   * ``sweep_mesh(n_devices)`` builds the mesh (a function, never a
-    module-level constant — the ``launch/mesh.py`` idiom — so importing
-    this module never touches jax device state).
+    module-level constant, so importing this module never touches jax
+    device state).
   * ``ShardedGrid`` is the one grid program: it flattens the grid's
     axes to one batch axis, pads it to a device multiple, and runs the
     vmapped per-point round scan under ``jax.pmap`` over the mesh's
@@ -94,9 +94,9 @@ def sweep_mesh(n_devices: Optional[int] = None) -> Mesh:
     """A 1-D device mesh over the first ``n_devices`` devices (default:
     all), axis name ``"grid"`` — the batch axis of every sharded driver.
 
-    A function, not a module constant (the ``launch/mesh.py`` idiom):
-    importing this module never touches jax device state, and tests force
-    a CPU device count via ``XLA_FLAGS`` before the first jax call."""
+    A function, not a module constant: importing this module never
+    touches jax device state, and tests force a CPU device count via
+    ``XLA_FLAGS`` before the first jax call."""
     devs = jax.devices()
     n = len(devs) if n_devices is None else int(n_devices)
     if not 1 <= n <= len(devs):

@@ -1,8 +1,8 @@
 import os
 import sys
 
-# Tests must see the default single CPU device (the 512-device override is
-# for the dry-run driver ONLY).
+# Tests run on the CPU, one device unless XLA_FLAGS forces more (the
+# sharded-sweep suite adapts to either).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -14,7 +14,7 @@ import math
 from conftest import require_or_skip_hypothesis
 
 require_or_skip_hypothesis()
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from repro.workload.synth import (  # noqa: E402
     DiurnalArrivals,
@@ -40,13 +40,16 @@ def test_poisson_empirical_rate_within_ci(rate, seed):
     assert abs(mean_iat - 1.0 / rate) <= 5.0 / (rate * math.sqrt(n - 1))
 
 
-@settings(max_examples=8, deadline=None)
+@settings(max_examples=8, deadline=None, derandomize=True)
 @given(
     rate=st.floats(0.5, 10.0),
     d0=st.floats(1.0, 20.0),
     d1=st.floats(1.0, 20.0),
     seed=st.integers(0, 2**31 - 1),
 )
+# the dwell-weighted mean (r*d0 + r*d1) / (d0 + d1) rounds to one ulp
+# below r here: the rate is right, exact float equality is not
+@example(rate=1.8861860137670954, d0=1.0, d1=1.5, seed=0)
 def test_mmpp_equal_rates_degenerate_to_poisson(rate, d0, d1, seed):
     """With equal regime rates the MMPP IS a homogeneous Poisson process
     whatever the dwell times — the regime-crossing IAT accounting must
@@ -55,7 +58,7 @@ def test_mmpp_equal_rates_degenerate_to_poisson(rate, d0, d1, seed):
     the degenerate case)."""
     n = 600
     proc = MMPPArrivals(rates=(rate, rate), dwell=(d0, d1), seed=seed)
-    assert proc.mean_rate == rate
+    assert math.isclose(proc.mean_rate, rate, rel_tol=1e-12, abs_tol=0.0)
     _, span = _span(proc, n)
     mean_iat = span / (n - 1)
     assert abs(mean_iat - 1.0 / rate) <= 5.0 / (rate * math.sqrt(n - 1))

@@ -1,9 +1,12 @@
+import collections
 import math
 
 import pytest
 
+from repro.core import base
+from repro.core.metrics import TaskRecord
 from repro.sim.simulator import run_simulation
-from repro.workload.synth import yahoo_like_trace
+from repro.workload.synth import synthetic_trace, yahoo_like_trace
 from repro.workload.traces import Job, Workload
 
 
@@ -62,3 +65,41 @@ def test_pigeon_reserved_workers_prioritize_short():
     m = run_simulation("pigeon", wl, num_workers=128).summary()
     # short jobs must not fare worse than long jobs under priority queuing
     assert m["short_median_delay"] <= m["long_median_delay"] + 1e-9
+
+
+class _CountedLaunches(TaskRecord):
+    """A task record that counts its launches: every write of a real
+    start time is one launch onto a worker."""
+
+    def __setattr__(self, key, value):
+        if key == "start_time" and not math.isnan(value):
+            object.__setattr__(self, "launches", getattr(self, "launches", 0) + 1)
+        object.__setattr__(self, key, value)
+
+
+@pytest.mark.parametrize("load", (0.5, 0.9))
+@pytest.mark.parametrize("name", ("megha", "sparrow", "eagle", "pigeon"))
+def test_event_backend_launches_each_task_once_on_a_free_worker(
+    monkeypatch, name, load
+):
+    """The event backend's invariants, without faults, at a light and a
+    heavy load: every task launches exactly once, finishes at its launch
+    time plus its duration, and no worker ever holds two tasks at once."""
+    monkeypatch.setattr(base, "TaskRecord", _CountedLaunches)
+    W = 128
+    wl = synthetic_trace(num_jobs=30, tasks_per_job=32, load=load,
+                         num_workers=W, seed=21)
+    kw = dict(num_gms=4, num_lms=4) if name == "megha" else {}
+    m = run_simulation(name, wl, num_workers=W, **kw)
+    assert len(m.tasks) == wl.num_tasks
+    assert len({(t.job_id, t.task_index) for t in m.tasks}) == wl.num_tasks
+    by_worker = collections.defaultdict(list)
+    for t in m.tasks:
+        assert getattr(t, "launches", 0) == 1, (t.job_id, t.task_index)
+        assert t.finish_time == t.start_time + t.duration, t
+        assert 0 <= t.placed_worker < W, t
+        by_worker[t.placed_worker].append((t.start_time, t.finish_time))
+    for w, spans in by_worker.items():
+        spans.sort()
+        for (_, end), (start, _) in zip(spans, spans[1:]):
+            assert start >= end, (w, end, start)

@@ -2,6 +2,7 @@
 and the (seed x load) sweep driver."""
 
 import dataclasses
+import functools
 import random
 
 import jax
@@ -16,6 +17,7 @@ from repro.sim.simulator import run_simulation
 from repro.simx import SimxConfig, engine, export_workload
 from repro.simx import eagle as simx_eagle
 from repro.simx import megha as simx_megha
+from repro.simx import oracle as simx_oracle
 from repro.simx import pigeon as simx_pigeon
 from repro.simx import sparrow as simx_sparrow
 from repro.simx import sweep as simx_sweep
@@ -27,11 +29,27 @@ from repro.workload.traces import Job, Workload
 #: yet fast on the event backend.
 PARITY = dict(num_jobs=40, tasks_per_job=64, load=0.8, num_workers=256, seed=7)
 W = PARITY["num_workers"]
+DT = 0.01
+
+#: Per-load parity settings: the seeds whose job delays are pooled, and the
+#: absolute slack on each delay percentile.  At load 0.5 the median sits
+#: where jobs that queued meet jobs that did not, so one seed's median moves
+#: by a quarter between seeds on the event backend alone (sparrow: 0.46 to
+#: 0.58 s over seeds 0-5); three seeds pool 120 jobs.  There megha's
+#: uncontended median is the 3-hop launch (1.5 ms), which the round-
+#: synchronous backend delays to the next round: the engine's approximation
+#: contract allows up to ``dt`` per scheduling reaction.
+PARITY_AT = {0.8: dict(seeds=(0,), abs=0.0), 0.5: dict(seeds=(0, 1, 2), abs=DT)}
+
+
+@functools.lru_cache(maxsize=None)
+def _parity_trace(load):
+    return synthetic_trace(**dict(PARITY, load=load))
 
 
 @pytest.fixture(scope="module")
 def parity_trace():
-    return synthetic_trace(**PARITY)
+    return _parity_trace(PARITY["load"])
 
 
 def _delays(m):
@@ -43,33 +61,50 @@ def _done(m):
     return sum(1 for t in m.tasks if t.finish_time == t.finish_time)
 
 
+@pytest.mark.parametrize("load", sorted(PARITY_AT, reverse=True))
 @pytest.mark.parametrize("scheduler", ["megha", "sparrow", "eagle", "pigeon"])
-def test_event_simx_parity(parity_trace, scheduler):
+def test_event_simx_parity(scheduler, load):
+    trace = _parity_trace(load)
+    seeds, slack = PARITY_AT[load]["seeds"], PARITY_AT[load]["abs"]
     kw = (
         dict(num_gms=4, num_lms=4, heartbeat_interval=1.0)
         if scheduler == "megha"
         else {}
     )
-    ev = run_simulation(scheduler, parity_trace, num_workers=W, seed=0, **kw)
-    sx = run_simulation(
-        scheduler, parity_trace, num_workers=W, seed=0, backend="simx", dt=0.01, **kw
-    )
-    # identical task counts, all completed
-    assert _done(ev) == _done(sx) == parity_trace.num_tasks
-    p50_ev, p95_ev = _delays(ev)
-    p50_sx, p95_sx = _delays(sx)
-    assert p50_sx == pytest.approx(p50_ev, rel=0.15)
-    assert p95_sx == pytest.approx(p95_ev, rel=0.15)
+    evs, sxs = [], []
+    for seed in seeds:
+        ev = run_simulation(scheduler, trace, num_workers=W, seed=seed, **kw)
+        sx = run_simulation(
+            scheduler, trace, num_workers=W, seed=seed, backend="simx", dt=DT,
+            **kw,
+        )
+        # identical task counts, all completed
+        assert _done(ev) == _done(sx) == trace.num_tasks
+        evs.append(ev)
+        sxs.append(sx)
+    d_ev = [d for m in evs for d in m.job_delays()]
+    d_sx = [d for m in sxs for d in m.job_delays()]
+    for q in (50, 95):
+        assert percentile(d_sx, q) == pytest.approx(
+            percentile(d_ev, q), rel=0.15, abs=slack
+        ), q
+
+    def total(field):
+        return (sum(getattr(m, field) for m in evs),
+                sum(getattr(m, field) for m in sxs))
+
     if scheduler == "megha":
         # both backends must exhibit the eventually-consistent signature
-        assert ev.inconsistencies > 0 and sx.inconsistencies > 0
-        assert ev.repartitions > 0 and sx.repartitions > 0
+        assert min(total("inconsistencies")) > 0
+        assert min(total("repartitions")) > 0
     elif scheduler in ("sparrow", "eagle"):
         # all-short trace: no SSS rejections, so probe counts match exactly
-        assert ev.probes == sx.probes > 0
+        ev_probes, sx_probes = total("probes")
+        assert ev_probes == sx_probes > 0
     else:
         # arrival + launch messages are trace-determined for pigeon
-        assert ev.messages == sx.messages > 0
+        ev_msgs, sx_msgs = total("messages")
+        assert ev_msgs == sx_msgs > 0
 
 
 @pytest.fixture(scope="module")
@@ -108,7 +143,9 @@ def small():
     return cfg, tasks, engine.estimate_rounds(cfg, tasks)
 
 
-@pytest.mark.parametrize("mod", [simx_megha, simx_sparrow, simx_eagle, simx_pigeon])
+@pytest.mark.parametrize(
+    "mod", [simx_megha, simx_sparrow, simx_eagle, simx_pigeon, simx_oracle]
+)
 def test_determinism_across_identical_seeds(small, mod):
     cfg, tasks, rounds = small
     a = mod.simulate_fixed(cfg, tasks, 5, rounds)
@@ -119,7 +156,9 @@ def test_determinism_across_identical_seeds(small, mod):
     assert int(a.inconsistencies) == int(b.inconsistencies)
 
 
-@pytest.mark.parametrize("mod", [simx_megha, simx_sparrow, simx_eagle, simx_pigeon])
+@pytest.mark.parametrize(
+    "mod", [simx_megha, simx_sparrow, simx_eagle, simx_pigeon, simx_oracle]
+)
 def test_vmap_over_seeds(small, mod):
     cfg, tasks, rounds = small
     seeds = jnp.arange(3)

@@ -1,6 +1,8 @@
 """Round-space fault injection (repro.simx.faults, Fig. 4):
 
-* the empty schedule is a bitwise no-op on every scheduler;
+* the empty schedule is a bitwise no-op on every scheduler, and the
+  Fig. 4 grid program with an empty plan reports the Fig. 2 grid's
+  numbers bitwise for every rule;
 * events-vs-simx parity holds under an identical mid-run fail_worker +
   fail_gm/recover_gm schedule;
 * crash waves / GM windows perturb delays but never lose work;
@@ -11,6 +13,7 @@
 import dataclasses
 import random
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -70,6 +73,37 @@ def test_empty_schedule_is_bitwise_noop(mixed, mod):
     for counter in ("messages", "probes", "inconsistencies", "repartitions"):
         assert int(getattr(a, counter)) == int(getattr(b, counter))
     assert int(b.lost) == 0
+
+
+@pytest.mark.parametrize("name", ("megha", "sparrow", "eagle", "pigeon", "oracle"))
+def test_fault_grid_with_empty_plan_equals_the_plain_grid(name):
+    """The two grid programs differ only in the fault schedule: the Fig. 4
+    ``fault_sweep_grid`` run with an all-inf severity row reports, point
+    for point and bit for bit, what the Fig. 2 ``sweep_grid`` reports on
+    the same trace and seeds."""
+    W, G = 64, 2
+    cfg = SimxConfig(num_workers=W, num_gms=G, num_lms=2, dt=0.05,
+                     heartbeat_interval=1.0)
+    tasks = export_workload(
+        synthetic_trace(num_jobs=8, tasks_per_job=16, load=0.9,
+                        num_workers=W, seed=6)
+    )
+    rounds = engine.estimate_rounds(cfg, tasks)
+    seeds = jnp.arange(2)
+    plain = simx_sweep.sweep_grid(
+        name, cfg, tasks, tasks.submit[None], tasks.job_submit[None], seeds,
+        rounds,
+    )
+    faulted = simx_sweep.fault_sweep_grid(
+        name, cfg, tasks,
+        jax.tree.map(lambda x: x[None], empty_schedule(W, G)), seeds, rounds,
+    )
+    assert set(plain) == set(faulted)
+    for key in plain:
+        assert plain[key].shape == faulted[key].shape == (1, 2), key
+        np.testing.assert_array_equal(
+            np.asarray(faulted[key]), np.asarray(plain[key]), err_msg=key)
+    assert (np.asarray(plain["tasks_done"]) == tasks.num_tasks).all()
 
 
 @pytest.mark.parametrize("mod", ALL_MODS)

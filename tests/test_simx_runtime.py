@@ -7,9 +7,12 @@ oracle:
   p50/p95 job delay lower-bounds every other scheduler on the shared
   parity trace (the paper's "partial knowledge costs delay" claim,
   quantified);
-* ``make_chunk_runner`` returns its all-done flag from inside the jitted
-  chunk (no second device round-trip per chunk) and matches the plain
-  scan bitwise;
+* ``make_chunk_runner``, the runner the chip benchmark times, returns its
+  all-done flag from inside the jitted chunk (no second device round-trip
+  per chunk) and matches the plain scan bitwise, for every rule at chunk
+  sizes 1, 8 and 64;
+* ``engine.estimate_rounds`` is a budget every rule finishes in, on the
+  synthetic trace and on Table 1's heavy-tailed mixtures;
 * ``sweep.point_summary`` and ``SimxRun`` report through ONE in-jit
   job-delay reduction (``runtime.job_delays_from_state``), pinned equal;
 * a hypothesis property over ALL registered rules (random trace x random
@@ -19,6 +22,7 @@ oracle:
   bound.
 """
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +40,11 @@ from repro.simx import (
 from repro.analysis.specs import check_state, dims_for
 from repro.simx import oracle as simx_oracle
 from repro.simx import sweep as simx_sweep
-from repro.workload.synth import synthetic_trace
+from repro.workload.synth import (
+    google_like_trace,
+    synthetic_trace,
+    yahoo_like_trace,
+)
 
 #: The shared parity trace of tests/test_simx.py — the acceptance surface
 #: for the oracle lower bound.
@@ -139,27 +147,80 @@ def test_oracle_empty_schedule_is_bitwise_noop():
     assert int(b.lost) == 0
 
 
-def test_chunk_runner_done_flag_matches_plain_scan():
-    """Satellite: the fused all-done flag is computed inside the jitted
-    chunk, agrees with the host-side probe, and the chunked state equals
-    the plain scan bitwise; run_to_completion still stops exactly."""
+#: 64 rounds of a 64-worker trace: every chunk size below divides it
+CHUNK_ROUNDS = 64
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_case(name):
+    """(step, initial state, the plain 64-round scan) of one rule, shared
+    by the chunk sizes."""
     tasks = export_workload(
         synthetic_trace(num_jobs=6, tasks_per_job=16, load=0.7,
                         num_workers=64, seed=2)
     )
-    cfg = SimxConfig(num_workers=64, dt=0.05)
-    rule = RULES["oracle"]
+    cfg = SimxConfig(num_workers=64, num_gms=2, num_lms=2, dt=0.05,
+                     heartbeat_interval=1.0)
+    rule = RULES[name]
     step = rule.build_step(cfg, tasks, jax.random.PRNGKey(0))
     state0 = rule.init(cfg, tasks)
-    runner = engine.make_chunk_runner(step, chunk=16)
-    s1, done1 = runner(state0)
-    ref = runtime.scan_rounds(step, state0, 16)
-    assert jnp.array_equal(s1.task_finish, ref.task_finish)
-    assert bool(done1) == bool(jnp.all(s1.task_finish <= s1.t))
-    final = engine.run_to_completion(step, state0, chunk=16, max_rounds=100_000)
+    return step, state0, runtime.scan_rounds(step, state0, CHUNK_ROUNDS)
+
+
+@pytest.mark.parametrize("chunk", (1, 8, 64))
+@pytest.mark.parametrize("name", ("megha", "sparrow", "eagle", "pigeon", "oracle"))
+def test_chunk_runner_done_flag_matches_plain_scan(name, chunk):
+    """The chip benchmark's runner, every rule: repeated
+    ``make_chunk_runner`` calls up to 64 rounds reach the plain scan's
+    state bitwise on every leaf; the fused all-done flag is computed inside
+    the jitted chunk and agrees with the host-side probe; and
+    run_to_completion still stops exactly."""
+    step, state0, ref = _chunk_case(name)
+    runner = engine.make_chunk_runner(step, chunk=chunk)
+    s1 = state0
+    for _ in range(CHUNK_ROUNDS // chunk):
+        s1, done1 = runner(s1)
+        assert bool(done1) == bool(jnp.all(s1.task_finish <= s1.t))
+    assert int(s1.rnd) == CHUNK_ROUNDS
+    la, lb = jax.tree.leaves(s1), jax.tree.leaves(ref)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    final = engine.run_to_completion(
+        step, state0, chunk=chunk, max_rounds=100_000, runner=runner)
     assert bool(jnp.all(final.task_finish <= final.t))
     # the early exit fired: nowhere near the runaway budget
     assert int(final.rnd) < 100_000
+
+
+#: Small traces of each generator at load 0.9 on 128 workers: the fixed
+#: one-second tasks of the synthetic trace, and Table 1's heavy-tailed
+#: short/long mixtures (Pareto tails up to 50x a 45 s long-task mean)
+BUDGET_TRACES = {
+    "synthetic": lambda: synthetic_trace(
+        num_jobs=12, tasks_per_job=32, load=0.9, num_workers=128, seed=4),
+    "google": lambda: google_like_trace(
+        num_jobs=60, total_tasks=1200, load=0.9, num_workers=128, seed=4),
+    "yahoo": lambda: yahoo_like_trace(
+        num_jobs=60, total_tasks=1200, load=0.9, num_workers=128, seed=5),
+}
+
+
+@pytest.mark.parametrize("trace", sorted(BUDGET_TRACES))
+@pytest.mark.parametrize("name", ("megha", "sparrow", "eagle", "pigeon", "oracle"))
+def test_estimate_rounds_is_enough_for_every_rule(name, trace):
+    """``engine.estimate_rounds`` is the round budget every driver sizes
+    its run by: run to exactly that budget, every task has finished."""
+    tasks = export_workload(BUDGET_TRACES[trace]())
+    cfg = _cfg(128, dt=0.05)
+    rule = RULES[name]
+    step = rule.build_step(cfg, tasks, jax.random.PRNGKey(0))
+    budget = engine.estimate_rounds(cfg, tasks)
+    final = engine.run_to_completion(
+        step, rule.init(cfg, tasks), chunk=1024, max_rounds=budget)
+    assert int(final.rnd) <= budget
+    done = np.asarray(final.task_finish <= final.t)
+    assert done.all(), f"{int((~done).sum())} of {tasks.num_tasks} unfinished"
 
 
 def test_point_summary_and_simx_run_share_one_delay_reduction():

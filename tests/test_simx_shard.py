@@ -94,7 +94,7 @@ def test_fig2_parity(rule):
         )
 
 
-@pytest.mark.parametrize("rule", ("megha", "sparrow"))
+@pytest.mark.parametrize("rule", sorted(RULES))
 def test_fig4_parity(rule):
     serial = sxs.fig4_sweep(rule, **FIG4)
     sharded = sxsh.sharded_fig4_sweep(rule, mesh=sxsh.sweep_mesh(), **FIG4)
@@ -160,7 +160,7 @@ def _one_shot_grid(plan):
             .reshape((rows, cols) + v.shape[2:]) for k, v in out.items()}
 
 
-@pytest.mark.parametrize("rule", ("megha", "sparrow"))
+@pytest.mark.parametrize("rule", sorted(RULES))
 def test_chunked_grid_equals_the_whole_budget_bitwise(rule):
     """Whole chunks and an uneven remainder (235 = 3 x 64 + 43 rounds)
     give summaries bit-identical to the grid run to its budget in one
@@ -187,7 +187,7 @@ def test_chunked_grid_equals_the_whole_budget_bitwise(rule):
 SMALL = dict(FIG2, loads=(0.35, 0.95))
 
 
-@pytest.mark.parametrize("rule", ("megha", "sparrow"))
+@pytest.mark.parametrize("rule", sorted(RULES))
 def test_every_point_equals_its_single_datacenter_runner(rule):
     """After ``k`` chunks each grid point's state is, leaf for leaf, the
     state ``engine.make_chunk_runner`` reaches in ``k`` chunks on that
@@ -306,7 +306,7 @@ def _mk_arrivals(load):
     )
 
 
-@pytest.mark.parametrize("rule", ("megha", "oracle"))
+@pytest.mark.parametrize("rule", sorted(RULES))
 def test_steady_state_parity(rule):
     """The lane-batched driver reproduces the serial streaming driver
     lane-for-lane: sketch estimates, exact retired delays, counters."""
@@ -387,8 +387,8 @@ def test_compile_cache_knob(env_set, tmp_path, monkeypatch):
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
         assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
     finally:
-        # the knob is process-global — leaked on, it corrupts later
-        # suites (the orbax checkpoint tests abort under an active cache)
+        # the knob is process-global — leaked on, every later compile in
+        # this process would read and write the test's cache directory
         for k, v in saved.items():
             jax.config.update(k, v)
         compilation_cache.reset_cache()

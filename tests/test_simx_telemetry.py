@@ -18,7 +18,10 @@
   round-trips through JSON as pure counter/metadata events;
 * the ``full_width_rounds`` counter: 1 in the rounds whose queue passes
   ran at the cap, because the longest reservation queue outgrew the
-  narrow width.
+  narrow width;
+* the two fixed-trace drivers: ``run_to_completion_telemetry`` reaches
+  ``run_to_completion``'s final state bitwise for every rule, whole
+  chunks and a budget cut inside one.
 """
 
 import dataclasses
@@ -83,6 +86,35 @@ def test_disabled_telemetry_is_bitwise_noop(name, trace):
         for x, y in zip(la, lb):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
         assert tl.num_samples == ROUNDS // stride
+
+
+@pytest.mark.parametrize("name", ("megha", "sparrow", "eagle", "pigeon", "oracle"))
+def test_telemetry_driver_reaches_the_plain_drivers_state(name, trace):
+    """``run_to_completion_telemetry`` is ``run_to_completion`` plus the
+    telemetry stage: from one initial state both drivers reach the same
+    final state bitwise, run to completion (whole chunks) and cut at a
+    budget that ends inside a chunk (100 = 64 + 4 windows of 8 + 4
+    unsampled rounds)."""
+    cfg, tasks = trace
+    rule = RULES[name]
+    key = jax.random.PRNGKey(0)
+    plain = rule.build_step(cfg, tasks, key)
+    tele = rule.build_step(cfg, tasks, key, telemetry=True)
+    tel = TelemetryConfig(stride=8)
+    for budget in (engine.estimate_rounds(cfg, tasks), 100):
+        a = engine.run_to_completion(
+            plain, rule.init(cfg, tasks), chunk=64, max_rounds=budget)
+        b, tl = engine.run_to_completion_telemetry(
+            tele, rule.init(cfg, tasks), tel, cfg, tasks, chunk=64,
+            max_rounds=budget)
+        assert int(a.rnd) == int(b.rnd) <= budget
+        la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        assert tl.num_samples == int(b.rnd) // 8
+    # the cut run ended mid-trace, so its tail rounds really ran
+    assert not bool(jnp.all(a.task_finish <= a.t))
 
 
 def test_timeline_series_shapes_and_units(trace):

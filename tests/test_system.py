@@ -8,7 +8,12 @@ import numpy as np
 import pytest
 
 from repro.sim.simulator import run_simulation
-from repro.workload.synth import downsampled, google_like_trace, yahoo_like_trace
+from repro.workload.synth import (
+    downsampled,
+    google_like_trace,
+    synthetic_trace,
+    yahoo_like_trace,
+)
 
 
 def test_prototype_style_comparison_megha_vs_pigeon():
@@ -25,15 +30,25 @@ def test_prototype_style_comparison_megha_vs_pigeon():
     assert sm["all_p95_delay"] <= sp["all_p95_delay"] + 1e-9
 
 
-def test_workload_statistics_match_table1_scale():
-    wl = yahoo_like_trace(num_jobs=500, total_tasks=20000, load=0.8,
-                          num_workers=3000, seed=1)
+#: Each Table 1 generator at its own DC size, cut to 500 jobs of 20,000
+#: tasks in all: (generator, DC size, keyword arguments)
+TABLE1 = {
+    "yahoo": (yahoo_like_trace, 3000, dict(total_tasks=20000, seed=1)),
+    "google": (google_like_trace, 13000, dict(total_tasks=20000, seed=2)),
+    "synthetic": (synthetic_trace, 10000, dict(tasks_per_job=40, seed=3)),
+}
+
+
+@pytest.mark.parametrize("gen", sorted(TABLE1))
+def test_workload_statistics_match_table1_scale(gen):
+    make, workers, kw = TABLE1[gen]
+    wl = make(num_jobs=500, load=0.8, num_workers=workers, **kw)
     s = wl.stats()
     assert s["num_jobs"] == 500
     assert abs(s["num_tasks"] - 20000) <= 1
     # effective load ~0.8 given span ~ num_jobs * mean_iat
     span = max(j.submit_time for j in wl.jobs)
-    load = s["demand_resource_seconds"] / (span * 3000)
+    load = s["demand_resource_seconds"] / (span * workers)
     assert 0.5 < load < 1.3
 
 
@@ -47,18 +62,6 @@ def test_delay_decomposition_accounts_for_total():
             if math.isnan(t.finish_time):
                 continue
             assert t.decomposition_residual() < 1e-9, (sched, t)
-
-
-def test_train_cli_end_to_end(tmp_path):
-    out = subprocess.run(
-        [sys.executable, "-m", "repro.launch.train",
-         "--arch", "qwen15_05b", "--preset", "tiny",
-         "--steps", "8", "--batch", "2", "--seq", "32",
-         "--ckpt-dir", str(tmp_path)],
-        capture_output=True, text=True, env=_env(), timeout=600,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "done:" in out.stdout
 
 
 def test_serve_cli_end_to_end():
